@@ -42,6 +42,7 @@ from repro.core import (EpisodePipeline, HybridConfig,          # noqa: E402
                         HybridEmbeddingTrainer, TieredEmbeddingTrainer,
                         build_episode_blocks)
 from repro.graph.generators import powerlaw_graph            # noqa: E402
+from repro.kernels import ops                                # noqa: E402
 from repro.runtime import FaultPlan, clear_plan, install_plan  # noqa: E402
 from repro.walk import MemorySampleStore, WalkConfig, WalkEngine  # noqa: E402
 
@@ -805,7 +806,7 @@ def main():
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         "smoke": args.smoke,
         "backend": jax.default_backend(),
-        "interpret_mode": jax.default_backend() != "tpu",
+        "interpret_mode": ops.interpret_mode(),
         "dtype": args.dtype,
         "nodes": nodes,
         "samples": samples,

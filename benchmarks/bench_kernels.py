@@ -134,7 +134,7 @@ def main():
     args = ap.parse_args()
 
     shapes = SMOKE_SHAPES if args.smoke else FULL_SHAPES
-    interpret = jax.default_backend() != "tpu"
+    interpret = ops.interpret_mode()
     # interpret-mode pallas is Python-slow: 1 timed iter is plenty there
     ref_iters = args.iters or (2 if args.smoke else 10)
     pallas_iters = args.iters or 1 if interpret else ref_iters

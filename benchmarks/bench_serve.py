@@ -43,6 +43,7 @@ from common import append_run                                # noqa: E402
 from repro.embed_serve import (MicroBatcher, ShardedEmbeddingStore,  # noqa: E402
                                drive_open_loop, overfetch_m, recall_at_k)
 from repro.embed_serve import topk as tk                     # noqa: E402
+from repro.kernels import ops                                # noqa: E402
 from repro.launch import roofline                            # noqa: E402
 
 # "quant" routes through the two-tier scan (int8 kernel on TPU, int8 jnp
@@ -88,7 +89,7 @@ def scan_bytes_model(store: ShardedEmbeddingStore, batch: int, k: int,
                          for sh in store.shards)
     kernel_path = impl == "pallas" or (
         impl in ("tiered",) + tuple(i for i in IMPLS if i.startswith("quant"))
-        and jax.default_backend() == "tpu")
+        and not ops.interpret_mode())
     scans = (-(-batch // tk.DEFAULT_BLOCK_Q)) if kernel_path else 1
     rescore = 0
     itemsize = store.shards[0].dtype.itemsize
@@ -205,7 +206,7 @@ def main():
     args = ap.parse_args()
 
     shapes = SMOKE_SHAPES if args.smoke else FULL_SHAPES
-    interpret = jax.default_backend() != "tpu"
+    interpret = ops.interpret_mode()
     iters = args.iters or (2 if args.smoke else 5)
     requests = args.requests or (32 if args.smoke else 256)
 

@@ -1,7 +1,9 @@
 """Benchmark harness entry point: one module per paper table/figure.
 
 ``PYTHONPATH=src python -m benchmarks.run [--only table4]``
-prints ``name,value,derived`` CSV lines (value in µs for timings).
+prints ``name,value,derived`` CSV lines (value in µs for timings). Every
+table runs in this one process (a process that holds a chip must not start
+children that need it); a table that raises fails the run with exit 1.
 """
 import argparse
 import importlib

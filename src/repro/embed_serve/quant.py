@@ -103,7 +103,8 @@ def rescore_exact(table, queries, cand_idx, *, k: int, gather: str = "xla",
     else:
         rows = jnp.take(table, safe, axis=0)
     rows = rows.reshape(Q, m, d).astype(jnp.float32)
-    scores = jnp.einsum("qd,qmd->qm", queries.astype(jnp.float32), rows)
+    scores = jnp.einsum("qd,qmd->qm", queries.astype(jnp.float32), rows,
+                        precision=jax.lax.Precision.HIGHEST)
     scores = jnp.where(idx == tk.IDX_SENTINEL, tk.NEG_INF, scores)
     return tk.select_topk(scores, idx, k)
 

@@ -50,12 +50,11 @@ import numpy as np
 from repro.core.partition import NodePartition
 from repro.embed_serve import quant as qz
 from repro.embed_serve import topk as tk
+from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.obs import counter_add, gauge_set
 from repro.runtime import fault_point
 from repro.train.checkpoint import load_arrays
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 QUERY_IMPLS = ("auto", "pallas", "rowwise", "xla",
                "quant", "quant_pallas", "quant_xla", "tiered")
@@ -213,21 +212,24 @@ class ShardedEmbeddingStore:
             # hot/cold split scans carry their own global-id maps
             return self._dispatch_shard_tiered(s, q, k, ov)
         shard = self.shards[s]
+        interpret = (kops.interpret_mode()
+                     if impl in ("pallas", "rowwise", "quant_pallas")
+                     else False)
         if impl == "pallas":
             v, i = tk.topk_mips(shard, q, k=k, valid=self.valid[s],
                                 block_n=self.block_n,
-                                interpret=not _ON_TPU)
+                                interpret=interpret)
         elif impl == "rowwise":
             v, i = tk.topk_mips_rowwise(shard, q, k=k,
                                         valid=self.valid[s],
-                                        interpret=not _ON_TPU)
+                                        interpret=interpret)
         elif impl.startswith("quant"):
             q8, sc = self.qshards[s]
             v, i = qz.topk_mips_quant_rescored(
                 shard, q8, sc, q, k=k, overfetch=ov,
                 valid=self.valid[s], block_n=self.block_n,
                 impl="pallas" if impl == "quant_pallas" else "xla",
-                interpret=not _ON_TPU)
+                interpret=interpret)
         else:
             v, i = tk.topk_mips_xla(shard, q, k=k, valid=self.valid[s])
         # shard-local → global node ids on the shard's own device
@@ -343,10 +345,11 @@ class ShardedEmbeddingStore:
         Compact → global maps live on the device, so like the plain path
         nothing syncs until the caller's device_get."""
         ht = self.hot_tiers[s]
+        interpret = kops.interpret_mode()   # kernels on TPU, jnp on CPU
         outs = []
         if ht.hot_valid > 0:
             kh = min(k, ht.hot_shard.shape[0])
-            if _ON_TPU:
+            if not interpret:
                 hv, hi = tk.topk_mips(
                     ht.hot_shard, q, k=kh, valid=ht.hot_valid,
                     block_n=min(self._tiered_bn, ht.hot_shard.shape[0]))
@@ -364,8 +367,7 @@ class ShardedEmbeddingStore:
                 ht.cold_shard, ht.cold_q8, ht.cold_sc, q, k=kc,
                 overfetch=ov, valid=ht.cold_valid,
                 block_n=min(self._tiered_bn, ht.cold_shard.shape[0]),
-                impl="pallas" if _ON_TPU else "xla",
-                interpret=not _ON_TPU)
+                impl="xla" if interpret else "pallas")
             cg = jnp.where(
                 ci == tk.IDX_SENTINEL, tk.IDX_SENTINEL,
                 jnp.take(ht.cold_map,
@@ -395,13 +397,15 @@ class ShardedEmbeddingStore:
                                jnp.asarray(np.stack(per_i)), k=k)
         return np.asarray(gv), np.asarray(gi)
 
-    def _resolve_impl(self, impl: str) -> str:
+    def resolve_impl(self, impl: str) -> str:
+        """The concrete scan path a query impl runs: "auto" is the Pallas
+        kernel on a TPU and plain jnp on the CPU, "quant" likewise."""
         if impl not in QUERY_IMPLS:
             raise ValueError(f"unknown impl {impl!r}; one of {QUERY_IMPLS}")
-        if impl == "auto":
-            impl = "pallas" if _ON_TPU else "xla"
-        elif impl == "quant":
-            impl = "quant_pallas" if _ON_TPU else "quant_xla"
+        if impl in ("auto", "quant"):
+            kernel = not kops.interpret_mode()
+            impl = {"auto": ("xla", "pallas"),
+                    "quant": ("quant_xla", "quant_pallas")}[impl][kernel]
         if impl.startswith("quant") and self.qshards is None:
             raise RuntimeError("store has no quantized tier; build it with "
                                "quant='int8'")
@@ -426,7 +430,7 @@ class ShardedEmbeddingStore:
 
         queries: (Q, d). Returns ((Q, k) f32 scores, (Q, k) i32 global node
         ids), k clamped to num_nodes. impl: "pallas" (the blocked DMA
-        kernel; interpret mode off-TPU), "rowwise" (reference kernel),
+        kernel; interpret mode on the CPU), "rowwise" (reference kernel),
         "xla" (plain jnp — the CPU serving path), "auto" (pallas on TPU,
         xla elsewhere), "quant" (the two-tier int8 scan + exact rescore —
         requires ``quant="int8"`` at load; kernel path on TPU, jnp path
@@ -441,7 +445,7 @@ class ShardedEmbeddingStore:
         return_meta=True appends a :class:`TopKMeta` (degraded flag +
         failed shard list) to the return tuple.
         """
-        impl = self._resolve_impl(impl)
+        impl = self.resolve_impl(impl)
         ov = self.overfetch if overfetch is None else overfetch
         k = min(k, self.num_nodes)
         q = jnp.asarray(np.asarray(queries, dtype=np.float32))

@@ -34,7 +34,7 @@ Kernels (all validated against :func:`repro.kernels.ref.topk_mips_ref`):
 Launch geometry: ``block_n=None`` (the default everywhere) sizes the scan
 tile with :func:`choose_block_n` — the serving mirror of
 ``kernels.ops.choose_block_b``, fitting the (2*bn, d) double-buffer
-scratch plus the merge working set against ``roofline.VMEM_BYTES``.
+scratch plus the merge working set against ``roofline.VMEM_BUDGET_BYTES``.
 
 Exactness: scores are f32 (tables cast up before the dot, like the SGNS
 kernels), selection is exact MIPS with ties broken toward the smaller row
@@ -43,8 +43,8 @@ Sentinels: invalid positions (padded table rows, masked candidates) carry
 (-inf, int32 max), so they lose every comparison and a shard with fewer
 than k valid rows degrades gracefully in the cross-shard merge.
 
-Interpret mode on CPU; TPU is the compilation target (lane-alignment
-follow-ons for the (bq, k) outputs are in the ROADMAP).
+Interpret mode on CPU; TPU is the compilation target
+(``tests/test_tpu_compile.py`` compiles the scans for a described v5e).
 """
 from __future__ import annotations
 
@@ -63,57 +63,68 @@ DEFAULT_BLOCK_Q = 128   # query rows per resident block (topk_mips default);
                         # the table is re-scanned once per query block
 DEFAULT_PLAN_K = 128    # running-candidate allowance choose_block_n assumes
                         # when the query-time k is not yet known (store load)
+# every score is an f32 product at full precision (the TPU's default rounds
+# f32 matmul operands to bf16), so rankings match the numpy oracle's
+_F32 = jax.lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------------
 # VMEM-aware tile planner (the serving mirror of kernels.ops.choose_block_b:
 # all decisions from static shape/dtype info, nothing at run time)
 # --------------------------------------------------------------------------
+def _lanes(n: int) -> int:
+    """Columns a VMEM buffer of n columns occupies (128-lane tiles)."""
+    return -(-n // 128) * 128
+
+
 def topk_scan_vmem_bytes(bn: int, d: int, dtype, *, k: int = DEFAULT_PLAN_K,
                          block_q: int = DEFAULT_BLOCK_Q,
                          hot_rows: int = 0) -> int:
     """Modeled VMEM working set of one topk_mips/topk_mips_quant launch.
 
-    Mirrors the scratch_shapes + compute temporaries: the (2*bn, d)
-    double-buffer tile slots (table dtype), the f32 cast of the scored
-    tile, the resident (bq, d) query block, the (bq, bn) score/iota
-    matrices, the (bq, k + bn) candidate concat the k-pass selection walks
-    (vals/idx plus the per-pass masks — modeled at 4 f32-width copies),
-    and the revisited (bq, k) output blocks.
+    Mirrors the scratch_shapes, the pipelined blocks and the compute
+    temporaries, with every column count padded to whole 128-lane tiles:
+    the (2*bn, d) double-buffer tile slots (table dtype), the f32 cast of
+    the scored tile, the double-buffered (bq, d) query block, the int8
+    scan's double-buffered (1, bn) scale block (8 sublanes), the (bq, bn)
+    score/iota matrices, the (bq, k + bn) candidates the selection loop
+    carries (vals/idx plus the per-pass masked copies — 4 f32-width
+    copies), and the double-buffered (bq, k) value/id output blocks.
 
     hot_rows models a co-resident hot-tier scan tile: the tiered store
     runs an exact-f32 scan over min(hot_rows, bn) rows alongside the quant
     scan of the cold remainder, so that tile's bytes come out of the same
-    budget. 0 (default) is the untiered model, byte-identical to before.
+    budget. 0 (default) is the untiered model.
     """
     item = jnp.dtype(dtype).itemsize
-    total = 2 * bn * d * item            # double-buffered tile slots
-    total += bn * d * 4                  # f32 cast of the scored tile
-    total += block_q * d * 4             # resident query block
-    total += block_q * bn * 4 * 2        # (bq, bn) scores + index iota
-    total += block_q * (k + bn) * 4 * 4  # select_topk candidate working set
-    total += block_q * k * 4 * 2         # running (bq, k) output blocks
-    total += min(hot_rows, bn) * d * 4   # exact hot-tier scan tile (f32)
+    total = 2 * bn * d * item                     # double-buffered tile slots
+    total += bn * d * 4                           # f32 cast of the scored tile
+    total += 2 * block_q * _lanes(d) * 4          # query block
+    if item == 1:
+        total += 2 * 8 * _lanes(bn) * 4           # int8 row-scale block
+    total += block_q * _lanes(bn) * 4 * 2         # (bq, bn) scores + iota
+    total += block_q * _lanes(k + bn) * 4 * 4     # selection candidates
+    total += 2 * 2 * block_q * _lanes(k) * 4      # running (bq, k) outputs
+    total += min(hot_rows, bn) * d * 4            # exact hot-tier scan tile
     return total
 
 
 def choose_block_n(d: int, dtype, *, k: int = DEFAULT_PLAN_K,
                    block_q: int = DEFAULT_BLOCK_Q,
-                   vmem_budget: int = roofline.VMEM_BYTES,
+                   vmem_budget: int = roofline.VMEM_BUDGET_BYTES,
                    hot_rows: int = 0) -> int:
     """Scan-tile rows from (d, dtype, k, block_q, VMEM budget).
 
     Largest power-of-two tile (cap 512 — past that the merge cost per tile
     grows without more DMA overlap to win) whose modeled working set fits
-    half the budget (headroom for compiler temporaries, same safety stance
-    as ``ops.choose_block_b``); floor 8 (f32 sublane). The (2*bn, d)
-    double-buffer scratch was previously unplanned — at d ≥ 4k an f32
-    bn=256 scratch alone busts a 16 MB budget.
+    the planner budget, which sits below the scoped VMEM limit every scan
+    launch requests (``roofline.VMEM_LIMIT_BYTES``; the gap is headroom
+    for compiler temporaries); floor 8 (f32 sublane).
     """
     bn = 512
     while bn > 8 and topk_scan_vmem_bytes(
             bn, d, dtype, k=k, block_q=block_q,
-            hot_rows=hot_rows) > vmem_budget // 2:
+            hot_rows=hot_rows) > vmem_budget:
         bn //= 2
     return bn
 
@@ -121,8 +132,10 @@ def choose_block_n(d: int, dtype, *, k: int = DEFAULT_PLAN_K,
 def select_topk(vals: jax.Array, idx: jax.Array, k: int):
     """Exact top-k over (Q, M) candidate (value, index) pairs.
 
-    k unrolled VPU-shaped passes: each selects the row-wise max value, and
-    among equal values the smallest index, then masks the taken slot to the
+    k VPU-shaped passes of one ``fori_loop`` (code size independent of k,
+    so k=100 or an over-fetched m=400 compiles as fast as k=10): each pass
+    selects the row-wise max value, and among equal values the smallest
+    index, writes it to output column j, then masks the taken slot to the
     (-inf, sentinel) pair. Shared by the kernels' per-tile merge (M = k +
     tile rows) and the cross-shard reduce (M = shards * k) so the tie rule
     cannot diverge between the two levels.
@@ -131,17 +144,24 @@ def select_topk(vals: jax.Array, idx: jax.Array, k: int):
     """
     vals = vals.astype(jnp.float32)
     idx = idx.astype(jnp.int32)
-    out_v, out_i = [], []
-    for _ in range(k):
-        v = jnp.max(vals, axis=1)
-        is_max = vals == v[:, None]
-        i = jnp.min(jnp.where(is_max, idx, IDX_SENTINEL), axis=1)
-        taken = is_max & (idx == i[:, None])
-        vals = jnp.where(taken, NEG_INF, vals)
-        idx = jnp.where(taken, IDX_SENTINEL, idx)
-        out_v.append(v)
-        out_i.append(i)
-    return jnp.stack(out_v, axis=1), jnp.stack(out_i, axis=1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (vals.shape[0], k), 1)
+
+    def take_one(j, carry):
+        vals, idx, out_v, out_i = carry
+        v = jnp.max(vals, axis=1, keepdims=True)
+        is_max = vals == v
+        i = jnp.min(jnp.where(is_max, idx, IDX_SENTINEL), axis=1,
+                    keepdims=True)
+        taken = is_max & (idx == i)
+        return (jnp.where(taken, NEG_INF, vals),
+                jnp.where(taken, IDX_SENTINEL, idx),
+                jnp.where(col == j, v, out_v),
+                jnp.where(col == j, i, out_i))
+
+    init = (vals, idx, jnp.full(col.shape, NEG_INF, jnp.float32),
+            jnp.full(col.shape, IDX_SENTINEL, jnp.int32))
+    _, _, out_v, out_i = jax.lax.fori_loop(0, k, take_one, init)
+    return out_v, out_i
 
 
 def _scored_tile(q_f32, tile, tile_start: jax.Array, valid: int, scale=None):
@@ -152,6 +172,7 @@ def _scored_tile(q_f32, tile, tile_start: jax.Array, valid: int, scale=None):
     f32 = jnp.float32
     scores = jax.lax.dot_general(q_f32, tile.astype(f32),
                                  (((1,), (1,)), ((), ())),
+                                 precision=_F32,
                                  preferred_element_type=f32)
     if scale is not None:
         scores = scores * scale
@@ -230,7 +251,7 @@ def _launch_topk_scan(table, scales, queries, *, k: int, valid: int,
     Qp = -(-Q // bq) * bq
     qp = jnp.pad(queries, ((0, Qp - Q), (0, 0)))
     grid = (Qp // bq, N // bn)        # table tiles innermost (sequential)
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]       # table (HBM)
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]       # table (HBM)
     operands = [table]
     if quant:
         in_specs.append(pl.BlockSpec((1, bn), lambda qi, t: (0, t)))
@@ -254,6 +275,8 @@ def _launch_topk_scan(table, scales, queries, *, k: int, valid: int,
             pltpu.VMEM((2 * bn, d), table.dtype),           # tile slots
             pltpu.SemaphoreType.DMA((2,)),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=roofline.VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
     return out_v[:Q], out_i[:Q]
@@ -353,8 +376,8 @@ def topk_mips_quant_xla(qtable, scales, queries, *, m: int,
     harmless, since tier two rescores exactly)."""
     N = qtable.shape[0]
     f32 = jnp.float32
-    scores = (queries.astype(f32) @ qtable.astype(f32).T
-              ) * scales.astype(f32).reshape(1, N)
+    scores = jnp.matmul(queries.astype(f32), qtable.astype(f32).T,
+                        precision=_F32) * scales.astype(f32).reshape(1, N)
     return _masked_select(scores, N if valid is None else valid, m)
 
 
@@ -373,6 +396,7 @@ def _topk_rowwise_kernel(row_ref, q_ref, out_v_ref, out_i_ref, *, k: int):
     score = jax.lax.dot_general(q_ref[...].astype(f32),
                                 row_ref[...].astype(f32),
                                 (((1,), (1,)), ((), ())),
+                                precision=_F32,
                                 preferred_element_type=f32)   # (Q, 1)
     gidx = jnp.full_like(score, t, dtype=jnp.int32)
     _merge_into(out_v_ref, out_i_ref, score, gidx, k)
@@ -417,7 +441,8 @@ def topk_mips_xla(table, queries, *, k: int, valid: int | None = None):
     and the jnp-level oracle for the kernels."""
     N = table.shape[0]
     f32 = jnp.float32
-    scores = queries.astype(f32) @ table.astype(f32).T
+    scores = jnp.matmul(queries.astype(f32), table.astype(f32).T,
+                        precision=_F32)
     return _masked_select(scores, N if valid is None else valid, k)
 
 

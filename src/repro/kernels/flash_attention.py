@@ -24,14 +24,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-if _COMPILER_PARAMS is None:  # fail at import, not deep inside pallas_call
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; extend this shim for the installed jax")
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
                   scale: float, causal: bool, window: int,
@@ -111,7 +103,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((tq, 1), jnp.float32),    # running denominator
             pltpu.VMEM((tq, hd), jnp.float32),   # output accumulator
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -12,7 +12,9 @@
                           row; no separate scatters, no (idx_c ++ idx_n)
                           concatenate). This is the production pallas path.
 
-Pallas kernels run in interpret mode on CPU, compiled on TPU.
+Pallas kernels compile for the TPU and run in interpret mode only when the
+default backend is the CPU (:func:`interpret_mode`, asked per call, so
+importing this module does not start a backend).
 
 `sgns_step` is the fused edge-minibatch update the hybrid trainer calls in
 its inner loop. Its kernel launch geometry — tile size ``block_b``, the
@@ -33,11 +35,18 @@ from repro.kernels import ref as _ref
 from repro.kernels import sgns as _k
 from repro.launch import roofline
 
-_ON_TPU = jax.default_backend() == "tpu"
 
-
-def _interpret() -> bool:
-    return not _ON_TPU
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted: True on the CPU backend,
+    False on a TPU, an error on any other platform (a kernel there would
+    neither compile nor be what users run)."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                       f"{backend!r}; use impl='ref'/'xla' there")
 
 
 # --------------------------------------------------------------------------
@@ -62,34 +71,50 @@ class FusedPlan:
     chunk_rows: int
 
 
-def fused_update_vmem_bytes(B: int, d: int, S: int, dtype,
-                            combine: str, staging_rows: int = 0) -> int:
-    """Modeled VMEM scratch for one sgns_fused_update launch of B rows.
+_LANE_ROW = 128 * 4    # bytes of one (rows, 1) 32-bit VMEM row: 128 lanes
 
-    Mirrors the scratch_shapes in kernels/sgns.py: gathered tables
-    (v/c/n, table dtype), f32 grads (dv/dc/dn), plus the combine's own
-    footprint — eq: the (B,B)/(B,S)/(S,S) equality matrices; segsum: the
-    sorted finals (table dtype) and f32 segment-prefix buffers.
+
+def fused_update_vmem_bytes(B: int, d: int, S: int, dtype,
+                            combine: str, staging_rows: int = 0,
+                            block_b: int = 256) -> int:
+    """Modeled VMEM of one sgns_fused_update launch of B rows.
+
+    Mirrors kernels/sgns.py: the scratch_shapes — gathered rows (v/c/n;
+    4 bytes per lane for f32 rows and for bf16 pair words alike), f32
+    grads (dv/dc/dn), and the combine's own footprint (eq: the (B,B)/(B,S)/
+    (S,S) equality matrices, twice for pair words, which also sum partner
+    rows; segsum: the sorted finals and f32 segment-prefix buffers, two
+    prefix buffers for pair words) — plus the pipelined blocks, each
+    double-buffered with every (rows, 1) column padded to 128 lanes: the
+    (bb, 1) mask tile, for bf16 the (bb, 1)/(S, 1) pair-half selectors, and
+    for eq the resident (B, 1)/(S, 1) index vectors.
 
     staging_rows models a co-resident cache-tier miss-staging block (the
     tiered trainer streams a (staging_rows, d) cold-row block alongside
-    the update); 0 — the default — is the resident path, byte-identical
-    to the pre-tiering model.
+    the update); 0 — the default — is the resident path.
     """
     item = jnp.dtype(dtype).itemsize
+    packed = item == 2
+    bb = min(block_b, B)
     L = B + S
-    total = (2 * B + S) * d * item          # v_s, c_s, n_s
+    Sp = -(-S // 8) * 8
+    total = (2 * B + S) * d * 4             # v_s, c_s, n_s (32-bit units)
     total += (2 * B + S) * d * 4            # dv_s, dc_s, dn_s
+    total += 2 * bb * _LANE_ROW             # mask tile
+    if packed:
+        total += 2 * (2 * bb + Sp) * _LANE_ROW      # pair-half selectors
     if combine == "eq":
-        total += (B * B + B * S + S * S) * 4
+        total += (B * B + B * S + S * S) * 4 * (2 if packed else 1)
+        total += 2 * (2 * B + Sp) * _LANE_ROW       # index vectors
     else:
-        total += (B + L) * d * item + L * d * 4   # fv_s, fc_s, ps_s
+        total += (B + L) * d * 4                    # fv_s, fc_s
+        total += L * d * 4 * (2 if packed else 1)   # ps_s (+ ps2_s)
     total += staging_rows * d * item        # cache miss-staging block
     return total
 
 
 def choose_block_b(B: int, d: int, S: int, dtype,
-                   vmem_budget: int = roofline.VMEM_BYTES,
+                   vmem_budget: int = roofline.VMEM_BUDGET_BYTES,
                    staging_rows: int = 0) -> int:
     """Pipeline tile rows from (B, d, S, dtype, VMEM budget).
 
@@ -99,9 +124,7 @@ def choose_block_b(B: int, d: int, S: int, dtype,
     enough that a tile's compute working set stays well under the budget.
     Batches past the cap get >= 2 grid steps automatically, which is where
     the double-buffered gather actually has a compute phase to hide behind;
-    small batches run a single tile (forcing 2 tiles at B <= 256 measurably
-    hurts on the interpret-mode container and saves nothing on TPU — the
-    whole gather is tiny).
+    small batches run a single tile.
     """
     # per-tile active rows: the gathered v/c tile slices (table dtype) plus
     # the f32 compute temporaries (v/c casts, dv/dc, the (bb, S) logits);
@@ -119,50 +142,49 @@ def choose_block_b(B: int, d: int, S: int, dtype,
 def plan_fused_update(B: int, d: int, S: int, dtype, *,
                       block_b: int | None = None,
                       combine: str | None = None,
-                      vmem_budget: int = roofline.VMEM_BYTES,
+                      vmem_budget: int = roofline.VMEM_BUDGET_BYTES,
                       staging_rows: int = 0) -> FusedPlan:
     """Pick (block_b, combine, chunk_rows) for a B-row fused update.
 
-    combine: equality-matrix reference while its O(B²) matrices fit the
-    budget, segment-sum beyond. chunk_rows: the largest block_b multiple
-    whose modeled scratch fits the budget (>= one tile even if nothing
-    "fits" — interpret mode has no real VMEM and TPU will simply spill).
+    combine: equality-matrix reference up to sgns.EQ_COMBINE_MAX_ROWS
+    padded rows while its O(B²) matrices fit the budget, segment-sum
+    beyond.
+    chunk_rows: the largest block_b multiple whose modeled VMEM fits the
+    budget (at least one tile). The budget sits below the scoped VMEM limit
+    every launch requests (``roofline.VMEM_LIMIT_BYTES``), and the model
+    counts the lane-padded pipelined blocks, so every plan it returns
+    compiles (``tests/test_tpu_compile.py``).
 
     Deliberate tradeoff when chunking kicks in: combine is decided from
     the WHOLE padded batch, so a batch too big for eq runs segsum chunks
-    sized by segsum's (smaller) footprint — the fewest launches. The
-    alternative — eq-sized chunks, each running the MXU-friendly combine —
-    means ~3x more launches, each re-DMAing the shared negatives and doing
-    B'² multiplies where segsum does B'·d adds; which side wins is a real-
-    TPU measurement (ROADMAP "VMEM model calibration"). Pass combine="eq"
-    with a pinned block_b to force eq-sized chunks for that experiment.
+    sized by segsum's (smaller) footprint — the fewest launches. Pass
+    combine="eq" with a pinned block_b to force eq-sized chunks.
 
     staging_rows reserves VMEM headroom for a co-resident cache-tier
-    miss-staging block (tiered trainer); 0 keeps the plan identical to
-    the pre-tiering model. NOTE: passing staging_rows to a call whose
-    result feeds sgns_step can change block_b and thus the f32 gradient
-    accumulation tiling — the tiered trainer therefore plans with the
-    SAME (block_b=None, staging_rows=0) geometry as the resident path and
-    uses this extended model only to validate that the geometry still
-    fits with the staging block co-resident.
+    miss-staging block (tiered trainer); 0 keeps the resident plan.
+    NOTE: passing staging_rows to a call whose result feeds sgns_step can
+    change block_b and thus the f32 gradient accumulation tiling — the
+    tiered trainer therefore plans with the SAME (block_b=None,
+    staging_rows=0) geometry as the resident path and uses this extended
+    model only to validate that the geometry still fits with the staging
+    block co-resident.
     """
     bb = block_b if block_b is not None else choose_block_b(
         B, d, S, dtype, vmem_budget, staging_rows)
     bb = min(bb, B)
     Bp = -(-B // bb) * bb               # rows after sgns_step's tile padding
+
+    def fits(rows, comb):
+        return fused_update_vmem_bytes(rows, d, S, dtype, comb, staging_rows,
+                                       block_b=bb) <= vmem_budget
     if combine is None:
-        combine = ("eq"
-                   if fused_update_vmem_bytes(Bp, d, S, dtype, "eq",
-                                              staging_rows) <= vmem_budget
+        combine = ("eq" if Bp <= _k.EQ_COMBINE_MAX_ROWS and fits(Bp, "eq")
                    else "segsum")
-    if fused_update_vmem_bytes(Bp, d, S, dtype, combine,
-                               staging_rows) <= vmem_budget:
+    if fits(Bp, combine):
         chunk = Bp                      # whole batch in one launch
     else:
         chunk = bb
-        while (chunk + bb < Bp
-               and fused_update_vmem_bytes(chunk + bb, d, S, dtype, combine,
-                                           staging_rows) <= vmem_budget):
+        while chunk + bb < Bp and fits(chunk + bb, combine):
             chunk += bb
     return FusedPlan(block_b=bb, combine=combine, chunk_rows=chunk)
 
@@ -192,7 +214,7 @@ def sgns_grads(v, c, n, mask, *, impl: str = "ref",
     bb = min(block_b, B)
     vp, cp, mp = (_pad_to(v, bb), _pad_to(c, bb), _pad_to(mask, bb))
     loss, dv, dc, dn = _k.sgns_grads(vp, cp, n, mp, block_b=bb,
-                                     interpret=_interpret())
+                                     interpret=interpret_mode())
     return loss, dv[:B], dc[:B], dn
 
 
@@ -209,7 +231,7 @@ def gather_rows(table, idx, *, impl: str = "ref", rows_per_block: int = 8):
     if impl == "ref":
         return _ref.gather_rows_ref(table, idx)
     return _k.gather_rows(table, idx, rows_per_block=rows_per_block,
-                          interpret=_interpret())
+                          interpret=interpret_mode())
 
 
 def scatter_add_rows(table, idx, upd, *, impl: str = "ref",
@@ -219,7 +241,7 @@ def scatter_add_rows(table, idx, upd, *, impl: str = "ref",
         return _ref.scatter_add_rows_ref(table, idx, upd)
     return _k.scatter_add_rows(table, idx, upd,
                                rows_per_block=rows_per_block,
-                               interpret=_interpret())
+                               interpret=interpret_mode())
 
 
 @functools.partial(jax.jit,
@@ -268,7 +290,7 @@ def sgns_step(vert, ctx, idx_v, idx_c, idx_n, mask, lr, *, impl: str = "ref",
                                    _pad_to(mask, bb))
                 return _k.sgns_fused_update(
                     vert, ctx, iv_p, ic_p, idx_n, m_p, lr_eff, block_b=bb,
-                    combine=plan.combine, interpret=_interpret())
+                    combine=plan.combine, interpret=interpret_mode())
             # chunked launches: B rows don't fit one launch's VMEM —
             # sequential fused updates over chunk_rows-row slices
             loss = jnp.float32(0.0)
@@ -280,7 +302,7 @@ def sgns_step(vert, ctx, idx_v, idx_c, idx_n, mask, lr, *, impl: str = "ref",
                 vert, ctx, lc = _k.sgns_fused_update(
                     vert, ctx, iv_c, ic_c, idx_n, m_c, lr_eff,
                     block_b=bb, combine=plan.combine,
-                    interpret=_interpret())
+                    interpret=interpret_mode())
                 loss = loss + lc
             return vert, ctx, loss
         # pallas_fused: one kernel for DMA-gather + grads (rows never
@@ -291,7 +313,7 @@ def sgns_step(vert, ctx, idx_v, idx_c, idx_n, mask, lr, *, impl: str = "ref",
                            _pad_to(mask, bb))
         loss, dv, dc, dn = _k.sgns_fused_grads(
             vert, ctx, iv_p, ic_p, idx_n, m_p, block_b=bb,
-            interpret=_interpret())
+            interpret=interpret_mode())
         vert = scatter_add_rows(vert, idx_v, -lr_eff * dv[:B], impl="pallas")
         idx_cn = jnp.concatenate([idx_c, idx_n])
         upd_cn = jnp.concatenate([-lr_eff * dc[:B], -lr_eff * dn])

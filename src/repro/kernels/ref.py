@@ -7,6 +7,8 @@ runs in this container (Pallas interpret mode is Python-slow).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,17 +28,20 @@ def sgns_grads_ref(v: jax.Array, c: jax.Array, n: jax.Array, mask: jax.Array):
       the summed loss w.r.t. v, c, n.
 
     Math: loss = Σ_b m_b [ softplus(-⟨v_b,c_b⟩) + Σ_s softplus(⟨v_b,n_s⟩) ].
+    Matmuls run at full f32 precision (the TPU's default rounds f32 operands
+    to bf16), like the kernels they check.
     """
     f32 = jnp.float32
+    mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
     v32, c32, n32 = v.astype(f32), c.astype(f32), n.astype(f32)
     m = mask.astype(f32)
     pos = jnp.sum(v32 * c32, axis=-1)                 # (B,)
-    neg = v32 @ n32.T                                 # (B, S)
+    neg = mm(v32, n32.T)                              # (B, S)
     g_pos = (jax.nn.sigmoid(pos) - 1.0) * m           # dL/dpos
     g_neg = jax.nn.sigmoid(neg) * m[:, None]          # dL/dneg
-    dv = g_pos[:, None] * c32 + g_neg @ n32           # (B, d)
+    dv = g_pos[:, None] * c32 + mm(g_neg, n32)        # (B, d)
     dc = g_pos[:, None] * v32                         # (B, d)
-    dn = g_neg.T @ v32                                # (S, d)
+    dn = mm(g_neg.T, v32)                             # (S, d)
     loss = jnp.sum(m * jax.nn.softplus(-pos)) + jnp.sum(
         m[:, None] * jax.nn.softplus(neg)
     )

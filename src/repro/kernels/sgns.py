@@ -27,8 +27,9 @@ Kernels:
   * ``*_rowwise``             — the original one-row-per-grid-step layouts,
     kept as the interpret-mode reference implementations.
 
-All are validated against ``ref.py`` in interpret mode (CPU container); TPU is
-the compilation target.
+All are validated against ``ref.py`` in interpret mode on the CPU;
+``tests/test_tpu_compile.py`` compiles the fused update for a described TPU
+v5e (the other kernels move single rows and compile for f32 tables only).
 """
 from __future__ import annotations
 
@@ -39,25 +40,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.launch import roofline
+
 
 # --------------------------------------------------------------------------
 # shared tile math: the SGNS fwd+bwd every kernel in this file runs on the
 # MXU. One definition so a formula fix can't silently diverge the kernels.
 # --------------------------------------------------------------------------
+def _dot(a, b, dims):
+    """f32 MXU matmul at full f32 precision (the TPU's default rounds f32
+    operands to bf16), so kernels agree with the f32 oracles on the chip."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _tile_grads(v, c, n, m):
     """v, c: (Bt, d); n: (S, d); m: (Bt, 1) — all f32.
     Returns (dv, dc, dn_tile, loss_tile) in f32."""
-    f32 = jnp.float32
     pos = jnp.sum(v * c, axis=-1, keepdims=True)               # (Bt, 1)
-    neg = jax.lax.dot_general(v, n, (((1,), (1,)), ((), ())),
-                              preferred_element_type=f32)      # (Bt, S) MXU
+    neg = _dot(v, n, ((1,), (1,)))                             # (Bt, S) MXU
     g_pos = (jax.nn.sigmoid(pos) - 1.0) * m                    # (Bt, 1)
     g_neg = jax.nn.sigmoid(neg) * m                            # (Bt, S)
-    dv = g_pos * c + jax.lax.dot_general(
-        g_neg, n, (((1,), (0,)), ((), ())), preferred_element_type=f32)
+    dv = g_pos * c + _dot(g_neg, n, ((1,), (0,)))
     dc = g_pos * v
-    dn_tile = jax.lax.dot_general(g_neg, v, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=f32)  # (S, d)
+    dn_tile = _dot(g_neg, v, ((0,), (0,)))                     # (S, d)
     loss_tile = (jnp.sum(m * jax.nn.softplus(-pos))
                  + jnp.sum(m * jax.nn.softplus(neg)))
     return dv, dc, dn_tile, loss_tile
@@ -245,6 +252,17 @@ def sgns_fused_grads(vert, ctx, idx_v, idx_c, idx_n, mask, *,
 # HBM. Padded rows (mask 0, index 0) fold in for free: their grads are zero,
 # and the combine makes them write row 0's correct final value.
 #
+# bf16 tables move as 32-bit pair words. Their HBM layout packs rows 2p and
+# 2p+1 into one 32-bit word per lane, and the TPU compiler refuses a one-row
+# DMA or a dynamic one-row VMEM access of such a table. So the kernel views
+# a bf16 table as (N/2, d) uint32 words (a free reshape to (N/2, 2, d) plus
+# an in-kernel ref bitcast), gathers the word row of each index's pair,
+# unpacks its half to f32 for the tile math, and writes whole pair words
+# back. Every writer of a pair must then carry both rows' final values: the
+# combine also sums the grads of each position's partner row (row ^ 1), so
+# all writers of a pair still emit identical bytes. A pair word is 4 bytes
+# per lane, so a bf16 gather moves as many bytes as an f32 one.
+#
 # Two duplicate-combine strategies (`combine=`):
 #   * "eq"     — (B, B) equality-matrix matmuls (MXU-friendly). O(B²) VMEM:
 #                the reference path, caps B per launch at ~2k rows (f32).
@@ -257,44 +275,120 @@ def sgns_fused_grads(vert, ctx, idx_v, idx_c, idx_n, mask, *,
 # --------------------------------------------------------------------------
 _NWRITE = 4   # write-back semaphore ring depth (max outstanding row writes)
 
-# largest B for which a direct sgns_fused_update call auto-selects the
-# equality-matrix combine ((B, B) f32 = 4 MB here); ops.plan_fused_update
-# makes the production decision from the full VMEM model instead
-_EQ_COMBINE_MAX_B = 1024
+# Largest B given the equality-matrix combine, by a direct
+# sgns_fused_update call and by ops.plan_fused_update. Past it the (B, B)
+# matrices may still fit VMEM, but the TPU compiler unrolls them into vector
+# code whose compile time grows as B² (2 s at B=256, 21 s at 1024, 314 s at
+# 3072 for a described v5e), so segsum takes over.
+EQ_COMBINE_MAX_ROWS = 512
+
+_HI16 = 0xFFFF0000
 
 
-def _fused_main_body(i, iv_ref, ic_ref, in_ref, vert_hbm, ctx_hbm, mask_ref,
+def compiler_params():
+    """The scoped VMEM the planners size fused-update launches for."""
+    return pltpu.CompilerParams(vmem_limit_bytes=roofline.VMEM_LIMIT_BYTES)
+
+
+def _word_of(packed: bool):
+    """Table row index → index of the word row a DMA moves."""
+    return (lambda r: r >> 1) if packed else (lambda r: r)
+
+
+def _unpack(w, odd):
+    """f32 values of one bf16 half of (R, d) uint32 pair words: the high
+    half (row 2p+1) where `odd`, else the low half (row 2p)."""
+    bits = jnp.where(odd, w & jnp.uint32(_HI16), w << 16)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _pack(even, odd):
+    """Pair words from the f32 values of rows 2p and 2p+1, each rounded
+    to bf16."""
+    def bits(x):
+        return jax.lax.bitcast_convert_type(
+            x.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32)
+    return bits(odd) | (bits(even) >> 16)
+
+
+def _to_words(t):
+    """(N, d) bf16 → (N/2, d) uint32 pair words, row 2p in the low half —
+    the TPU's own packing, done by XLA for interpret mode."""
+    n, d = t.shape
+    return jax.lax.bitcast_convert_type(
+        t.reshape(n // 2, 2, d).swapaxes(1, 2), jnp.uint32)
+
+
+def _from_words(w, dtype):
+    m, d = w.shape
+    return jax.lax.bitcast_convert_type(w, dtype).swapaxes(1, 2).reshape(
+        2 * m, d)
+
+
+def _word_ref(ref):
+    """The (rows, d) units the kernel moves: the table itself (f32, or the
+    interpret-mode uint32 words), or the uint32 pair words of a bf16 table
+    handed over as (N/2, 2, d)."""
+    if len(ref.shape) == 3:
+        w = ref.bitcast(jnp.uint32)
+        return w.reshape(w.shape[0], w.shape[2])
+    return ref
+
+
+def _sgd_rows(w, odd, own, par, lr):
+    """Words to write back for positions whose pre-update words are w.
+
+    Unpacked (par None): w - lr * own, the update cast to the table dtype
+    first like the ref's scatter-add of a cast update. Packed: both bf16
+    halves of each pair word — the position's own row (half `odd`, grads
+    `own`) and its partner row (grads `par`; zero leaves it unchanged)."""
+    if par is None:
+        return w + (-lr * own).astype(w.dtype)
+
+    def upd(x, g):
+        return x + (-lr * g).astype(jnp.bfloat16).astype(jnp.float32)
+    mine = upd(_unpack(w, odd), own)
+    other = upd(_unpack(w, jnp.logical_not(odd)), par)
+    return _pack(jnp.where(odd, other, mine), jnp.where(odd, mine, other))
+
+
+def _fused_main_body(i, iv_ref, ic_ref, in_ref, vw, cw, mask_ref, odd_refs,
                      loss_ref, v_s, c_s, n_s, dv_s, dc_s, dn_s, gsem, nsem):
     """Shared per-grid-step body of both fused-update kernels: the double-
-    buffered row-gather pipeline + MXU tile grads + loss/dn accumulation."""
+    buffered row-gather pipeline + MXU tile grads + loss/dn accumulation.
+    odd_refs is None (unpacked) or the (bb,1)/(bb,1)/(S,1) half selectors
+    of the v/c tiles and the negatives (packed)."""
     T = pl.num_programs(0)
     bb = mask_ref.shape[0]
     S = n_s.shape[0]
     f32 = jnp.float32
+    word = _word_of(odd_refs is not None)
 
     def tile_copies(t, op):
         """start/wait the 2*bb row DMAs of tile t on sem slot t % 2."""
         def body(j, _):
             r = t * bb + j
             getattr(pltpu.make_async_copy(
-                vert_hbm.at[iv_ref[r]], v_s.at[r], gsem.at[t % 2]), op)()
+                vw.at[word(iv_ref[r])], v_s.at[r], gsem.at[t % 2]), op)()
             getattr(pltpu.make_async_copy(
-                ctx_hbm.at[ic_ref[r]], c_s.at[r], gsem.at[t % 2]), op)()
+                cw.at[word(ic_ref[r])], c_s.at[r], gsem.at[t % 2]), op)()
             return 0
         jax.lax.fori_loop(0, bb, body, 0)
+
+    def neg_copy(s):
+        return pltpu.make_async_copy(cw.at[word(in_ref[s])], n_s.at[s], nsem)
 
     @pl.when(i == 0)
     def _prologue():
         # shared negatives: start first so they overlap tile 0's gathers
         def nstart(s, _):
-            pltpu.make_async_copy(ctx_hbm.at[in_ref[s]], n_s.at[s],
-                                  nsem).start()
+            neg_copy(s).start()
             return 0
         jax.lax.fori_loop(0, S, nstart, 0)
         tile_copies(0, "start")
+
         def nwait(s, _):
-            pltpu.make_async_copy(ctx_hbm.at[in_ref[s]], n_s.at[s],
-                                  nsem).wait()
+            neg_copy(s).wait()
             return 0
         jax.lax.fori_loop(0, S, nwait, 0)
 
@@ -304,10 +398,14 @@ def _fused_main_body(i, iv_ref, ic_ref, in_ref, vert_hbm, ctx_hbm, mask_ref,
 
     tile_copies(i, "wait")
 
+    def rows(x, k):
+        return (x.astype(f32) if odd_refs is None
+                else _unpack(x, odd_refs[k][...] == 1))
+
     dv, dc, dn_tile, loss_tile = _tile_grads(
-        v_s[pl.ds(i * bb, bb), :].astype(f32),
-        c_s[pl.ds(i * bb, bb), :].astype(f32),
-        n_s[...].astype(f32), mask_ref[...].astype(f32))
+        rows(v_s[pl.ds(i * bb, bb), :], 0),
+        rows(c_s[pl.ds(i * bb, bb), :], 1),
+        rows(n_s[...], 2), mask_ref[...].astype(f32))
     dv_s[pl.ds(i * bb, bb), :] = dv
     dc_s[pl.ds(i * bb, bb), :] = dc
 
@@ -320,25 +418,24 @@ def _fused_main_body(i, iv_ref, ic_ref, in_ref, vert_hbm, ctx_hbm, mask_ref,
     loss_ref[...] += loss_tile
 
 
-def _write_rows(src, idx_sref, tbl_out, count, wsem):
+def _write_rows(src, idx_sref, tbl_out, count, wsem, word):
     """Pipelined row write-back: semaphore ring, _NWRITE in flight."""
+    def copy(p):
+        return pltpu.make_async_copy(src.at[p], tbl_out.at[word(idx_sref[p])],
+                                     wsem.at[p % _NWRITE])
+
     def body(p, _):
         @pl.when(p >= _NWRITE)
         def _retire():
-            q = p - _NWRITE
-            pltpu.make_async_copy(
-                src.at[q], tbl_out.at[idx_sref[q]],
-                wsem.at[q % _NWRITE]).wait()
-        pltpu.make_async_copy(src.at[p], tbl_out.at[idx_sref[p]],
-                              wsem.at[p % _NWRITE]).start()
+            copy(p - _NWRITE).wait()
+        copy(p).start()
         return 0
     jax.lax.fori_loop(0, count, body, 0)
     for p in range(max(0, count - _NWRITE), count):   # drain
-        pltpu.make_async_copy(src.at[p], tbl_out.at[idx_sref[p]],
-                              wsem.at[p % _NWRITE]).wait()
+        copy(p).wait()
 
 
-def _write_rows_unique(src, upos_ref, idx_sref, tbl_out, count, wsem):
+def _write_rows_unique(src, upos_ref, idx_sref, tbl_out, count, wsem, word):
     """Deduplicated pipelined write-back: one row DMA per *unique* scatter
     target instead of one per position.
 
@@ -350,40 +447,39 @@ def _write_rows_unique(src, upos_ref, idx_sref, tbl_out, count, wsem):
     bytes are identical because every position of a run emits the same
     final row, so this also retires the old benign write race.
     """
+    def copy(p):
+        u = upos_ref[p]
+        return pltpu.make_async_copy(src.at[u], tbl_out.at[word(idx_sref[u])],
+                                     wsem.at[p % _NWRITE])
+
     def body(p, _):
         @pl.when(p >= _NWRITE)
         def _retire():
-            q = p - _NWRITE
-            pltpu.make_async_copy(
-                src.at[upos_ref[q]], tbl_out.at[idx_sref[upos_ref[q]]],
-                wsem.at[q % _NWRITE]).wait()
-        pltpu.make_async_copy(
-            src.at[upos_ref[p]], tbl_out.at[idx_sref[upos_ref[p]]],
-            wsem.at[p % _NWRITE]).start()
+            copy(p - _NWRITE).wait()
+        copy(p).start()
         return 0
     jax.lax.fori_loop(0, count, body, 0)
 
     def drain(p, _):
-        pltpu.make_async_copy(
-            src.at[upos_ref[p]], tbl_out.at[idx_sref[upos_ref[p]]],
-            wsem.at[p % _NWRITE]).wait()
+        copy(p).wait()
         return 0
     jax.lax.fori_loop(jnp.maximum(count - _NWRITE, 0), count, drain, 0)
 
 
 def _sgns_update_kernel(iv_ref, ic_ref, in_ref,               # scalar prefetch
                         vert_hbm, ctx_hbm, ivv_ref, icv_ref, inv_ref,
-                        mask_ref, lr_ref,
-                        vert_out, ctx_out, loss_ref,
-                        v_s, c_s, n_s, dv_s, dc_s, dn_s,
-                        gsem, nsem, wsem):
+                        mask_ref, lr_ref, *rest, packed: bool):
+    odd_refs = rest[:3] if packed else None
+    (vert_out, ctx_out, loss_ref, v_s, c_s, n_s, dv_s, dc_s, dn_s,
+     gsem, nsem, wsem) = rest[3:] if packed else rest
     i = pl.program_id(0)
     T = pl.num_programs(0)
-    B, d = v_s.shape
-    S = n_s.shape[0]
+    B = v_s.shape[0]
     f32 = jnp.float32
-    _fused_main_body(i, iv_ref, ic_ref, in_ref, vert_hbm, ctx_hbm, mask_ref,
-                     loss_ref, v_s, c_s, n_s, dv_s, dc_s, dn_s, gsem, nsem)
+    word = _word_of(packed)
+    _fused_main_body(i, iv_ref, ic_ref, in_ref, _word_ref(vert_hbm),
+                     _word_ref(ctx_hbm), mask_ref, odd_refs, loss_ref,
+                     v_s, c_s, n_s, dv_s, dc_s, dn_s, gsem, nsem)
 
     @pl.when(i == T - 1)
     def _apply():
@@ -391,27 +487,34 @@ def _sgns_update_kernel(iv_ref, ic_ref, in_ref,               # scalar prefetch
         iv = ivv_ref[...]                                    # (B, 1) i32
         ic = icv_ref[...]
         inn = inv_ref[...]                                   # (S, 1) i32
-        dot = functools.partial(jax.lax.dot_general,
-                                preferred_element_type=f32)
-        # duplicate-combine: position-level grad sums per table row
-        eq_vv = (iv == iv.reshape(1, B)).astype(f32)         # (B, B)
-        dvsum = dot(eq_vv, dv_s[...], (((1,), (0,)), ((), ())))
-        eq_cc = (ic == ic.reshape(1, B)).astype(f32)         # (B, B)
-        eq_cn = (ic == inn.reshape(1, S)).astype(f32)        # (B, S)
-        eq_nn = (inn == inn.reshape(1, S)).astype(f32)       # (S, S)
-        dcsum = (dot(eq_cc, dc_s[...], (((1,), (0,)), ((), ())))
-                 + dot(eq_cn, dn_s[...], (((1,), (0,)), ((), ()))))
-        dnsum = (dot(eq_cn, dc_s[...], (((0,), (0,)), ((), ())))
-                 + dot(eq_nn, dn_s[...], (((1,), (0,)), ((), ()))))
-        # in-place SGD (update cast to table dtype first, like the ref's
-        # scatter-add of a cast update)
-        v_s[...] = v_s[...] + (-lr * dvsum).astype(v_s.dtype)
-        c_s[...] = c_s[...] + (-lr * dcsum).astype(c_s.dtype)
-        n_s[...] = n_s[...] + (-lr * dnsum).astype(n_s.dtype)
 
-        _write_rows(v_s, iv_ref, vert_out, B, wsem)
-        _write_rows(c_s, ic_ref, ctx_out, B, wsem)
-        _write_rows(n_s, in_ref, ctx_out, S, wsem)
+        def esum(keys, idx, grads):
+            """Σ of grads over positions whose row equals each key."""
+            eq = (keys == idx.reshape(1, -1)).astype(f32)
+            return _dot(eq, grads, ((1,), (0,)))
+
+        # duplicate-combine: position-level grad sums per table row; ctx
+        # rows sum over both the positive-context and the negative grads
+        def vsum(keys):
+            return esum(keys, iv, dv_s[...])
+
+        def csum(keys):
+            return esum(keys, ic, dc_s[...]) + esum(keys, inn, dn_s[...])
+
+        def apply(buf, keys, total):
+            if packed:
+                buf[...] = _sgd_rows(buf[...], (keys & 1) == 1, total(keys),
+                                     total(keys ^ 1), lr)
+            else:
+                buf[...] = _sgd_rows(buf[...], None, total(keys), None, lr)
+        apply(v_s, iv, vsum)
+        apply(c_s, ic, csum)
+        apply(n_s, inn, csum)
+
+        _write_rows(v_s, iv_ref, _word_ref(vert_out), B, wsem, word)
+        _write_rows(c_s, ic_ref, _word_ref(ctx_out), B, wsem, word)
+        _write_rows(n_s, in_ref, _word_ref(ctx_out), n_s.shape[0], wsem,
+                    word)
 
 
 def _sgns_update_kernel_segsum(iv_ref, ic_ref, in_ref,        # scalar prefetch
@@ -419,10 +522,7 @@ def _sgns_update_kernel_segsum(iv_ref, ic_ref, in_ref,        # scalar prefetch
                                pc_ref, icns_ref, cflag_ref,
                                uv_ref, nv_ref, uc_ref, nc_ref,
                                vert_hbm, ctx_hbm, mask_ref, lr_ref,
-                               vert_out, ctx_out, loss_ref,
-                               v_s, c_s, n_s, dv_s, dc_s, dn_s,
-                               fv_s, fc_s, ps_s,
-                               gsem, nsem, wsem):
+                               *rest, packed: bool):
     """Fused update with the sort-based segment-sum duplicate-combine.
 
     The host argsorted the index vectors: pv/pc map sorted position → batch
@@ -441,50 +541,77 @@ def _sgns_update_kernel_segsum(iv_ref, ic_ref, in_ref,        # scalar prefetch
     DMA per run (uv/uc list each run's last sorted position, nv/nc count
     the runs) instead of one per position — on skewed batches the hub rows
     that dominate collapse to single writes.
+
+    Packed (bf16) tables run over pair words: a run is a row pair, the
+    forward pass keeps one accumulator per half (ps/ps2), and every position
+    emits the pair word with both rows' finals.
     """
+    odd_refs = rest[:3] if packed else None
+    rest = rest[3:] if packed else rest
+    vert_out, ctx_out, loss_ref, v_s, c_s, n_s, dv_s, dc_s, dn_s = rest[:9]
+    fv_s, fc_s, ps_s = rest[9:12]
+    ps2_s = rest[12] if packed else None
+    gsem, nsem, wsem = rest[-3:]
     i = pl.program_id(0)
     T = pl.num_programs(0)
     B, d = v_s.shape
     S = n_s.shape[0]
     L = B + S
     f32 = jnp.float32
-    _fused_main_body(i, iv_ref, ic_ref, in_ref, vert_hbm, ctx_hbm, mask_ref,
-                     loss_ref, v_s, c_s, n_s, dv_s, dc_s, dn_s, gsem, nsem)
+    word = _word_of(packed)
+    _fused_main_body(i, iv_ref, ic_ref, in_ref, _word_ref(vert_hbm),
+                     _word_ref(ctx_hbm), mask_ref, odd_refs, loss_ref,
+                     v_s, c_s, n_s, dv_s, dc_s, dn_s, gsem, nsem)
 
     @pl.when(i == T - 1)
     def _apply():
         lr = lr_ref[0, 0]
 
-        def combine(count, perm_ref, flag_ref, grad_row, orig_row, out_buf):
+        def combine(count, perm_ref, sidx_ref, flag_ref, grad_row, orig_row,
+                    out_buf):
             zero = jnp.zeros((1, d), f32)
 
             def fwd(j, acc):
                 g = grad_row(perm_ref[j])
-                acc = jnp.where((flag_ref[j] & 1) == 1, g, acc + g)
-                ps_s[pl.ds(j, 1), :] = acc
-                return acc
-            jax.lax.fori_loop(0, count, fwd, zero)
+                start = (flag_ref[j] & 1) == 1
+                if not packed:
+                    acc = jnp.where(start, g, acc + g)
+                    ps_s[pl.ds(j, 1), :] = acc
+                    return acc
+                odd = (sidx_ref[j] & 1) == 1
+                acc_e = (jnp.where(start, zero, acc[0])
+                         + jnp.where(odd, zero, g))
+                acc_o = (jnp.where(start, zero, acc[1])
+                         + jnp.where(odd, g, zero))
+                ps_s[pl.ds(j, 1), :] = acc_e
+                ps2_s[pl.ds(j, 1), :] = acc_o
+                return acc_e, acc_o
+            jax.lax.fori_loop(0, count, fwd, (zero, zero) if packed else zero)
 
             def bwd(t, tot):
                 j = count - 1 - t
-                tot = jnp.where((flag_ref[j] & 2) == 2,
-                                ps_s[pl.ds(j, 1), :], tot)
-                # same op structure as the eq path's in-place SGD: the
-                # combined update is cast to the table dtype, the add runs
-                # in the table dtype
-                out_buf[pl.ds(j, 1), :] = (
-                    orig_row(perm_ref[j]) + (-lr * tot).astype(out_buf.dtype))
-                return tot
-            jax.lax.fori_loop(0, count, bwd, zero)
+                last = (flag_ref[j] & 2) == 2
+                w = orig_row(perm_ref[j])
+                if not packed:
+                    tot = jnp.where(last, ps_s[pl.ds(j, 1), :], tot)
+                    # same op structure as the eq path's in-place SGD
+                    out_buf[pl.ds(j, 1), :] = _sgd_rows(w, None, tot, None,
+                                                        lr)
+                    return tot
+                te = jnp.where(last, ps_s[pl.ds(j, 1), :], tot[0])
+                to = jnp.where(last, ps2_s[pl.ds(j, 1), :], tot[1])
+                out_buf[pl.ds(j, 1), :] = _sgd_rows(w, False, te, to, lr)
+                return te, to
+            jax.lax.fori_loop(0, count, bwd, (zero, zero) if packed else zero)
 
-        combine(B, pv_ref, vflag_ref,
+        combine(B, pv_ref, ivs_ref, vflag_ref,
                 lambda p: dv_s[pl.ds(p, 1), :],
                 lambda p: v_s[pl.ds(p, 1), :], fv_s)
 
         # ctx side runs over the concatenated (idx_c ++ idx_n) position
         # space: p < B is a positive-context grad, p >= B a shared-negative
-        # grad — this is exactly the cross-coupling the eq path's eq_cn
-        # blocks provided
+        # grad — this is exactly the cross-coupling the eq path's ctx sums
+        # over both index vectors provide
         def c_grad(p):
             pc = jnp.minimum(p, B - 1)
             pn = jnp.maximum(p - B, 0)
@@ -497,10 +624,12 @@ def _sgns_update_kernel_segsum(iv_ref, ic_ref, in_ref,        # scalar prefetch
             return jnp.where(p < B, c_s[pl.ds(pc, 1), :],
                              n_s[pl.ds(pn, 1), :])
 
-        combine(L, pc_ref, cflag_ref, c_grad, c_orig, fc_s)
+        combine(L, pc_ref, icns_ref, cflag_ref, c_grad, c_orig, fc_s)
 
-        _write_rows_unique(fv_s, uv_ref, ivs_ref, vert_out, nv_ref[0], wsem)
-        _write_rows_unique(fc_s, uc_ref, icns_ref, ctx_out, nc_ref[0], wsem)
+        _write_rows_unique(fv_s, uv_ref, ivs_ref, _word_ref(vert_out),
+                           nv_ref[0], wsem, word)
+        _write_rows_unique(fc_s, uc_ref, icns_ref, _word_ref(ctx_out),
+                           nc_ref[0], wsem, word)
 
 
 def _run_flags(sorted_idx):
@@ -530,6 +659,24 @@ def _unique_write_plan(sorted_idx):
     return upos, (rank[-1] + 1).reshape(1)
 
 
+def _kernel_tables(vert, ctx, packed: bool, interpret: bool):
+    """The table operands as the kernel takes them (see _word_ref)."""
+    if not packed:
+        return vert, ctx
+    if interpret:
+        return _to_words(vert), _to_words(ctx)
+    return (vert.reshape(vert.shape[0] // 2, 2, -1),
+            ctx.reshape(ctx.shape[0] // 2, 2, -1))
+
+
+def _user_table(t, dtype, packed: bool, interpret: bool):
+    if not packed:
+        return t
+    if interpret:
+        return _from_words(t, dtype)
+    return t.reshape(2 * t.shape[0], -1)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("block_b", "combine", "interpret"))
 def sgns_fused_update(vert, ctx, idx_v, idx_c, idx_n, mask, lr, *,
@@ -538,9 +685,11 @@ def sgns_fused_update(vert, ctx, idx_v, idx_c, idx_n, mask, lr, *,
     """One fully-fused SGNS SGD minibatch: gather + grads + apply in a single
     pallas_call with the tables aliased input→output.
 
-    vert: (Nv, d); ctx: (Nc, d) (same dtype); idx_v/idx_c: (B,); idx_n: (S,);
-    mask: (B,); lr: scalar. B must be a multiple of min(block_b, B) —
-    ops.sgns_step pads. Returns (vert', ctx', loss).
+    vert: (Nv, d); ctx: (Nc, d) (same dtype, f32 or bf16); idx_v/idx_c:
+    (B,); idx_n: (S,); mask: (B,); lr: scalar. B must be a multiple of
+    min(block_b, B) — ops.sgns_step pads. Returns (vert', ctx', loss).
+    bf16 tables with an odd row count pay a one-row pad copy per call (the
+    pair words need even rows); the trainers' tables have even row counts.
 
     ``combine`` selects the duplicate-combine strategy: ``"eq"`` (equality-
     matrix matmuls, O(B²) VMEM — the small-B reference), ``"segsum"``
@@ -552,8 +701,17 @@ def sgns_fused_update(vert, ctx, idx_v, idx_c, idx_n, mask, lr, *,
     d = vert.shape[1]
     S = idx_n.shape[0]
     assert vert.dtype == ctx.dtype, (vert.dtype, ctx.dtype)
+    assert vert.dtype in (jnp.float32, jnp.bfloat16), vert.dtype
+    packed = vert.dtype == jnp.bfloat16     # moved as uint32 pair words
+    if packed and (vert.shape[0] % 2 or ctx.shape[0] % 2):
+        nv, nc = vert.shape[0], ctx.shape[0]
+        v2, c2, loss = sgns_fused_update(
+            jnp.pad(vert, ((0, nv % 2), (0, 0))),
+            jnp.pad(ctx, ((0, nc % 2), (0, 0))), idx_v, idx_c, idx_n, mask,
+            lr, block_b=block_b, combine=combine, interpret=interpret)
+        return v2[:nv], c2[:nc], loss
     if combine is None:
-        combine = "eq" if B <= _EQ_COMBINE_MAX_B else "segsum"
+        combine = "eq" if B <= EQ_COMBINE_MAX_ROWS else "segsum"
     assert combine in ("eq", "segsum"), combine
     bb = min(block_b, B)
     assert B % bb == 0, (B, bb)
@@ -561,15 +719,17 @@ def sgns_fused_update(vert, ctx, idx_v, idx_c, idx_n, mask, lr, *,
     iv32 = idx_v.astype(jnp.int32)
     ic32 = idx_c.astype(jnp.int32)
     in32 = idx_n.astype(jnp.int32)
+    vk, ck = _kernel_tables(vert, ctx, packed, interpret)
+    wdt = jnp.uint32 if packed else vert.dtype     # the units the DMAs move
     out_shape = (
-        jax.ShapeDtypeStruct(vert.shape, vert.dtype),
-        jax.ShapeDtypeStruct(ctx.shape, ctx.dtype),
+        jax.ShapeDtypeStruct(vk.shape, vk.dtype),
+        jax.ShapeDtypeStruct(ck.shape, ck.dtype),
         jax.ShapeDtypeStruct((1, 1), f32),
     )
     table_scratch = [
-        pltpu.VMEM((B, d), vert.dtype),                  # v_s
-        pltpu.VMEM((B, d), ctx.dtype),                   # c_s
-        pltpu.VMEM((S, d), ctx.dtype),                   # n_s
+        pltpu.VMEM((B, d), wdt),                         # v_s
+        pltpu.VMEM((B, d), wdt),                         # c_s
+        pltpu.VMEM((S, d), wdt),                         # n_s
         pltpu.VMEM((B, d), f32),                         # dv_s
         pltpu.VMEM((B, d), f32),                         # dc_s
         pltpu.VMEM((S, d), f32),                         # dn_s
@@ -579,81 +739,98 @@ def sgns_fused_update(vert, ctx, idx_v, idx_c, idx_n, mask, lr, *,
         pltpu.SemaphoreType.DMA,                         # negatives
         pltpu.SemaphoreType.DMA((_NWRITE,)),             # write-back ring
     ]
+    # packed: which half of its pair word each v/c/n row is
+    odd_specs, odd_ops = [], []
+    if packed:
+        odd_specs = [pl.BlockSpec((bb, 1), lambda i, *_: (i, 0)),
+                     pl.BlockSpec((bb, 1), lambda i, *_: (i, 0)),
+                     pl.BlockSpec((S, 1), lambda i, *_: (0, 0))]
+        odd_ops = [(iv32 & 1).reshape(B, 1), (ic32 & 1).reshape(B, 1),
+                   (in32 & 1).reshape(S, 1)]
+    lr2 = jnp.asarray(lr, f32).reshape(1, 1)
     if combine == "eq":
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B // bb,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),        # vert (HBM)
-                pl.BlockSpec(memory_space=pltpu.ANY),        # ctx (HBM)
+                pl.BlockSpec(memory_space=pl.ANY),           # vert (HBM)
+                pl.BlockSpec(memory_space=pl.ANY),           # ctx (HBM)
                 pl.BlockSpec((B, 1), lambda i, *_: (0, 0)),  # idx_v as vector
                 pl.BlockSpec((B, 1), lambda i, *_: (0, 0)),  # idx_c as vector
                 pl.BlockSpec((S, 1), lambda i, *_: (0, 0)),  # idx_n as vector
                 pl.BlockSpec((bb, 1), lambda i, *_: (i, 0)),  # mask tile
                 pl.BlockSpec((1, 1), lambda i, *_: (0, 0)),  # lr
-            ],
+            ] + odd_specs,
             out_specs=(
-                pl.BlockSpec(memory_space=pltpu.ANY),        # vert' (aliased)
-                pl.BlockSpec(memory_space=pltpu.ANY),        # ctx'  (aliased)
+                pl.BlockSpec(memory_space=pl.ANY),           # vert' (aliased)
+                pl.BlockSpec(memory_space=pl.ANY),           # ctx'  (aliased)
                 pl.BlockSpec((1, 1), lambda i, *_: (0, 0)),  # loss (accum)
             ),
             scratch_shapes=table_scratch + sems,
         )
         vert2, ctx2, loss = pl.pallas_call(
-            _sgns_update_kernel,
+            functools.partial(_sgns_update_kernel, packed=packed),
             grid_spec=grid_spec,
             out_shape=out_shape,
             # operands 0..2 are the scalar-prefetch index vectors.
             input_output_aliases={3: 0, 4: 1},
+            compiler_params=compiler_params(),
             interpret=interpret,
-        )(iv32, ic32, in32, vert, ctx,
+        )(iv32, ic32, in32, vk, ck,
           iv32.reshape(B, 1), ic32.reshape(B, 1), in32.reshape(S, 1),
-          mask.reshape(B, 1), jnp.asarray(lr, f32).reshape(1, 1))
-        return vert2, ctx2, loss[0, 0]
+          mask.reshape(B, 1), lr2, *odd_ops)
+        return (_user_table(vert2, vert.dtype, packed, interpret),
+                _user_table(ctx2, ctx.dtype, packed, interpret), loss[0, 0])
 
     # segsum: sort each scatter index space once on the XLA side; the kernel
-    # combines duplicates over the sorted runs in O(B·d)
+    # combines duplicates over the sorted runs in O(B·d). Packed tables
+    # combine over row pairs: runs and write targets are pair indices (the
+    # row sort keeps each pair's rows adjacent)
     L = B + S
     perm_v = jnp.argsort(iv32).astype(jnp.int32)          # stable
     ivs = jnp.take(iv32, perm_v)
     icn = jnp.concatenate([ic32, in32])
     perm_c = jnp.argsort(icn).astype(jnp.int32)
     icns = jnp.take(icn, perm_c)
-    upos_v, nuniq_v = _unique_write_plan(ivs)
-    upos_c, nuniq_c = _unique_write_plan(icns)
+    shift = 1 if packed else 0
+    upos_v, nuniq_v = _unique_write_plan(ivs >> shift)
+    upos_c, nuniq_c = _unique_write_plan(icns >> shift)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=13,
         grid=(B // bb,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),            # vert (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),            # ctx (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),               # vert (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),               # ctx (HBM)
             pl.BlockSpec((bb, 1), lambda i, *_: (i, 0)),     # mask tile
             pl.BlockSpec((1, 1), lambda i, *_: (0, 0)),      # lr
-        ],
+        ] + odd_specs,
         out_specs=(
-            pl.BlockSpec(memory_space=pltpu.ANY),            # vert' (aliased)
-            pl.BlockSpec(memory_space=pltpu.ANY),            # ctx'  (aliased)
+            pl.BlockSpec(memory_space=pl.ANY),               # vert' (aliased)
+            pl.BlockSpec(memory_space=pl.ANY),               # ctx'  (aliased)
             pl.BlockSpec((1, 1), lambda i, *_: (0, 0)),      # loss (accum)
         ),
         scratch_shapes=table_scratch + [
-            pltpu.VMEM((B, d), vert.dtype),                  # fv_s (finals)
-            pltpu.VMEM((L, d), ctx.dtype),                   # fc_s (finals)
+            pltpu.VMEM((B, d), wdt),                         # fv_s (finals)
+            pltpu.VMEM((L, d), wdt),                         # fc_s (finals)
             pltpu.VMEM((L, d), f32),                         # ps_s (prefixes)
-        ] + sems,
+        ] + ([pltpu.VMEM((L, d), f32)] if packed else [])    # ps2_s (odd half)
+          + sems,
     )
     vert2, ctx2, loss = pl.pallas_call(
-        _sgns_update_kernel_segsum,
+        functools.partial(_sgns_update_kernel_segsum, packed=packed),
         grid_spec=grid_spec,
         out_shape=out_shape,
         # operands 0..12 are the scalar-prefetch index/permutation/dedup
         # vectors.
         input_output_aliases={13: 0, 14: 1},
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(iv32, ic32, in32,
-      perm_v, ivs, _run_flags(ivs), perm_c, icns, _run_flags(icns),
-      upos_v, nuniq_v, upos_c, nuniq_c,
-      vert, ctx, mask.reshape(B, 1), jnp.asarray(lr, f32).reshape(1, 1))
-    return vert2, ctx2, loss[0, 0]
+      perm_v, ivs, _run_flags(ivs >> shift), perm_c, icns,
+      _run_flags(icns >> shift), upos_v, nuniq_v, upos_c, nuniq_c,
+      vk, ck, mask.reshape(B, 1), lr2, *odd_ops)
+    return (_user_table(vert2, vert.dtype, packed, interpret),
+            _user_table(ctx2, ctx.dtype, packed, interpret), loss[0, 0])
 
 
 # --------------------------------------------------------------------------
@@ -697,7 +874,7 @@ def gather_rows(table, idx, *, rows_per_block: int = 8,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Bp // rb,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],    # table (HBM)
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],    # table (HBM)
         out_specs=pl.BlockSpec((rb, d), lambda i, idx: (i, 0)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((rb,))],
     )
@@ -832,10 +1009,10 @@ def scatter_add_rows(table, idx, upd, *, rows_per_block: int = 8,
         num_scalar_prefetch=2,
         grid=(Bp // rb,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),            # table: alias
+            pl.BlockSpec(memory_space=pl.ANY),            # table: alias
             pl.BlockSpec((rb, d), lambda i, *_: (i, 0)),     # upd block
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((rb, d), table.dtype),
             pltpu.SemaphoreType.DMA((rb,)),
@@ -874,7 +1051,7 @@ def scatter_add_rows_rowwise(table, idx, upd, *, interpret: bool = False):
         num_scalar_prefetch=1,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),           # table: alias only
+            pl.BlockSpec(memory_space=pl.ANY),           # table: alias only
             pl.BlockSpec((1, d), lambda i, idx: (i, 0)),    # upd row
         ],
         out_specs=pl.BlockSpec((1, d), lambda i, idx: (idx[i], 0)),

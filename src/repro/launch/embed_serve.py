@@ -39,7 +39,10 @@ import sys
 import numpy as np
 
 
-def main(argv=None):
+def main(argv=None, *, devices=None):
+    """CLI entry. ``devices`` pins the store's shards to those devices
+    instead of every device JAX finds. Returns recall@k (a failed gate
+    exits 1 instead)."""
     from repro.embed_serve import (MicroBatcher, ShardedEmbeddingStore,
                                    drive_open_loop, recall_at_k)
 
@@ -116,6 +119,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro import obs
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.embed_serve import quant as qz
     from repro.runtime import FaultPlan, clear_plan, install_plan
 
@@ -148,9 +153,11 @@ def main(argv=None):
         # "validate" an overfetch margin that was never exercised
         ap.error("--overfetch requires --quant int8")
     load_kw = {}
+    if devices is not None:
+        load_kw["devices"] = list(devices)
     if args.shards is not None:
         import jax
-        devs = jax.devices()
+        devs = list(jax.devices() if devices is None else devices)
         load_kw["devices"] = [devs[i % len(devs)] for i in range(args.shards)]
     if args.shard_timeout_ms is not None:
         load_kw["shard_timeout_s"] = args.shard_timeout_ms / 1e3
@@ -163,6 +170,9 @@ def main(argv=None):
     print(f"loaded {args.table} table: {store.num_nodes} x {store.dim} "
           f"{store.host_table.dtype} over {len(store.shards)} shard(s) "
           f"(step {store.step}){tier}")
+    resolved = store.resolve_impl(impl)
+    print(f"impl: {resolved}" + (f" (from --impl {args.impl})"
+                                 if resolved != args.impl else ""))
 
     plan = None
     if args.inject:
@@ -263,6 +273,7 @@ def main(argv=None):
         print(f"FAIL: recall {recall:.4f} < required {args.check_recall} "
               f"vs the {which} oracle")
         sys.exit(1)
+    return recall
 
 
 if __name__ == "__main__":

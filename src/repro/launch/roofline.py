@@ -1,8 +1,9 @@
-"""Roofline terms from a compiled dry-run artifact (no real hardware).
+"""Roofline terms from a compiled dry-run artifact, and the chip table.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI (DCN for the "pod" axis is slower; collectives whose
-replica groups span pods are reported separately when detectable).
+Peaks are keyed by ``jax.Device.device_kind``; a kind that is not in
+:data:`CHIPS` is an error, never a silent default. The dry-run (which
+compiles for a described chip, no hardware attached) models
+:data:`DRYRUN_KIND`.
 
 Methodology:
   * compute term   = per-device HLO FLOPs / peak  (cost_analysis runs on the
@@ -10,21 +11,60 @@ Methodology:
   * memory term    = per-device HLO bytes accessed / HBM bw
   * collective term = Σ (result bytes of all-gather / all-reduce /
     reduce-scatter / all-to-all / collective-permute in the per-device
-    module) / link bw. Result-shape bytes are a lower bound on the bytes a
-    device moves for that op (ring all-reduce moves ~2x); we report the raw
-    sum and note the factor.
+    module) / the chip's published interconnect bandwidth. Result-shape
+    bytes are a lower bound on the bytes a device moves for that op (ring
+    all-reduce moves ~2x); we report the raw sum and note the factor.
 """
 from __future__ import annotations
 
+import dataclasses
 import re
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s
-LINK_BW = 50e9               # bytes/s per ICI link
-VMEM_BYTES = 16 * 2**20      # on-chip vector memory per core (~16 MB); the
-                             # budget kernels.ops sizes fused-kernel scratch
-                             # against (compiled reality to be tightened on
-                             # real TPU — see ROADMAP)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks (bytes and operations per second)."""
+
+    bf16_flops: float
+    int8_ops: float
+    hbm_bytes: int
+    hbm_bw: float
+    ici_bw: float           # chip-to-chip interconnect, all links together
+    source: str
+
+
+CHIPS = {
+    # JAX reports a v5e chip as "TPU v5 lite"
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bytes=16 * 10**9,
+        hbm_bw=819e9, ici_bw=1600e9 / 8,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI'),
+}
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of one chip; unknown kinds raise."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(CHIPS)}") from None
+
+
+PEAK_FLOPS = CHIPS[DRYRUN_KIND].bf16_flops   # the dry-run's modeled chip
+HBM_BW = CHIPS[DRYRUN_KIND].hbm_bw
+LINK_BW = CHIPS[DRYRUN_KIND].ici_bw
+
+# Scoped VMEM every Pallas launch of the embedding kernels requests
+# (pltpu.CompilerParams.vmem_limit_bytes; a v5e core has 128 MiB, and the
+# compiler's default scope is 16 MiB), and the budget below it that the
+# launch planners (kernels.ops.plan_fused_update, embed_serve.topk
+# .choose_block_n) size a launch's modeled working set to. The gap is
+# headroom for what the models leave out (compiler temporaries, spills).
+VMEM_LIMIT_BYTES = 48 * 2**20
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES * 3 // 4
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

@@ -34,7 +34,9 @@ import time
 import numpy as np
 
 
-def train_embedding(args):
+def train_embedding(args, devices=None):
+    """The embedding trainer on a (1, len(devices)) mesh (default: every
+    device JAX finds). Returns (final-epoch AUC, the trainer)."""
     import jax
     from repro import obs
     from repro.configs.tencent_embedding import SMALL
@@ -88,8 +90,9 @@ def train_embedding(args):
     print(f"graph: {g.num_nodes} nodes / {g.num_edges} train edges; "
           f"{len(test_e)} held out")
 
-    n_dev = jax.device_count()
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    devices = list(jax.devices() if devices is None else devices)
+    mesh = jax.make_mesh((1, len(devices)), ("data", "model"),
+                         devices=devices)
     cfg_kw = {}
     if args.dtype is not None:          # None -> HybridConfig default (bf16)
         cfg_kw["dtype"] = args.dtype
@@ -129,7 +132,7 @@ def train_embedding(args):
               f"episode {start_episode}")
         if start_epoch >= args.epochs:
             print("resume cursor is past the final epoch; nothing to do")
-            return
+            return None, trainer
     else:
         trainer.init_embeddings()
 
@@ -207,10 +210,11 @@ def train_embedding(args):
     engine = mk_walker()
     engine.start_async(start_epoch)
     try:
-        _train_embedding_epochs(args, cfg, trainer, engine, store,
-                                pipe, test_e, neg_e, mk_walker=mk_walker,
-                                start_epoch=start_epoch,
-                                start_episode=start_episode)
+        auc = _train_embedding_epochs(args, cfg, trainer, engine, store,
+                                      pipe, test_e, neg_e,
+                                      mk_walker=mk_walker,
+                                      start_epoch=start_epoch,
+                                      start_episode=start_episode)
         if args.hbm_rows is not None:
             st = trainer.cache_stats()
             print(f"cache: hit_rate {st['hit_rate']:.3f} "
@@ -258,6 +262,7 @@ def train_embedding(args):
                   f"{obs_tracer.dropped} dropped)")
         if writer is not None or obs_tracer is not None:
             obs.disable()
+    return auc, trainer
 
 
 def _dump_diagnostics(out_dir, err, coord):
@@ -323,6 +328,7 @@ def _train_embedding_epochs(args, cfg, trainer, engine, store, pipe,
         t0 = time.perf_counter()
         nxt = None
         losses = []
+        dropped = 0
         # resuming mid-epoch: episodes before the cursor were already trained
         # into the restored tables — drain them from the walker's stream
         # without training so the bounded store keeps flowing
@@ -336,6 +342,7 @@ def _train_embedding_epochs(args, cfg, trainer, engine, store, pipe,
                     continue
                 pipe.prefetch_window(epoch, ep, args.episodes)
                 eb = pipe.get(epoch, ep)
+                dropped += eb.dropped
                 t_ep = time.perf_counter()
                 with span("train_episode", "train",
                           {"epoch": epoch, "episode": ep}):
@@ -379,7 +386,9 @@ def _train_embedding_epochs(args, cfg, trainer, engine, store, pipe,
         print(f"epoch {epoch:3d} loss {loss_s} AUC {auc:.4f} "
               f"({time.perf_counter()-t0:.1f}s)"
               + (f" [{len(pipe.recovered)} episode(s) re-walked]"
-                 if pipe.recovered else ""))
+                 if pipe.recovered else "")
+              + (f" [{dropped} samples dropped past --block-cap]"
+                 if dropped else ""))
         if epoch + 1 < args.epochs:
             engine = nxt
         if epoch + 1 == args.epochs:
@@ -391,6 +400,7 @@ def _train_embedding_epochs(args, cfg, trainer, engine, store, pipe,
     if args.min_auc is not None and auc < args.min_auc:
         raise SystemExit(
             f"final AUC {auc:.4f} below --min-auc {args.min_auc}")
+    return auc
 
 
 def train_lm(args):
@@ -441,7 +451,10 @@ def train_lm(args):
     pipe.close()
 
 
-def main(argv=None):
+def main(argv=None, *, devices=None):
+    """CLI entry. ``devices`` (embedding mode) pins the training mesh to
+    those devices instead of every device JAX finds; embedding mode returns
+    (final-epoch AUC, the trainer)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tencent-embedding")
     ap.add_argument("--seed", type=int, default=0)
@@ -591,9 +604,11 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=20)
     ap.add_argument("--save", action="store_true")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.arch == "tencent-embedding":
         args.lr = args.lr if args.lr is not None else 0.025
-        train_embedding(args)
+        return train_embedding(args, devices=devices)
     else:
         args.lr = args.lr if args.lr is not None else 3e-4
         train_lm(args)

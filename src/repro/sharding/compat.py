@@ -1,32 +1,19 @@
-"""Version-compat shims for jax APIs that moved between releases.
-
-The container pins an older jax than some call sites were written against;
-everything here resolves to the modern API when it exists and falls back to
-the equivalent older spelling otherwise, so the same source runs on both.
-"""
+"""Thin wrappers over jax sharding APIs the embedding step uses."""
 from __future__ import annotations
 
 import jax
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` appeared in jax 0.6; older releases expose the same
-    transform as ``jax.experimental.shard_map.shard_map`` with the replication
-    check named ``check_rep`` instead of ``check_vma`` (we disable it either
-    way: the episode step's tuple-of-subparts carry defeats the checker)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with the varying-manual-axes check off: the episode
+    step's tuple-of-subparts carry defeats the checker."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def axis_flat_index(axis_names, sizes):
     """Row-major flat index of this device across ``axis_names``, for use
-    inside shard_map. Mesh extents are passed statically: ``jax.lax
-    .axis_size`` is missing on older jax, and they must be python ints
-    anyway."""
+    inside shard_map. Mesh extents are passed statically (python ints)."""
     idx = jax.lax.axis_index(axis_names[0])
     for name, n in zip(axis_names[1:], sizes[1:]):
         idx = idx * n + jax.lax.axis_index(name)

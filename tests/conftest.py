@@ -5,52 +5,6 @@
 import numpy as np
 import pytest
 
-# ---------------------------------------------------------------------------
-# hypothesis is optional: several test modules use it for property tests, but
-# the training container doesn't ship it. Install a stub that lets those
-# modules import (so the rest of their tests run) and turns @given tests into
-# skips. Strategy constructors only need to be call-able at decoration time.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import sys
-    import types
-
-    def _strategy(*args, **kwargs):
-        return None
-
-    _st = types.ModuleType("hypothesis.strategies")
-    for _name in ("integers", "floats", "booleans", "sampled_from", "lists",
-                  "tuples", "just", "one_of", "text", "composite", "data"):
-        setattr(_st, _name, _strategy)
-
-    def _given(*args, **kwargs):
-        def deco(fn):
-            # a bare no-arg function — NOT functools.wraps(fn): preserving
-            # fn's signature would make pytest treat the @given kwargs as
-            # missing fixtures and error the test instead of skipping it
-            def wrapper():
-                pytest.skip("hypothesis not installed (stubbed in conftest)")
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            return wrapper
-        return deco
-
-    def _settings(*args, **kwargs):
-        def deco(fn):
-            return fn
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.HealthCheck = types.SimpleNamespace(
-        too_slow=None, data_too_large=None, filter_too_much=None)
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-
 
 @pytest.fixture(scope="session")
 def sbm_graph():

@@ -113,7 +113,8 @@ import dataclasses
 cfg = cfgs.get_config('phi3.5-moe-42b-a6.6b').reduced(layers=2, d_model=256,
                                                       experts=4)
 cfg = dataclasses.replace(cfg, train_microbatches=2)
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh((2, 2))
 params = tfm.init_params(jax.random.PRNGKey(0), cfg)
 p_sh = param_shardings(params, mesh)
 params = jax.device_put(params, p_sh)

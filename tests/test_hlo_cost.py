@@ -60,3 +60,17 @@ def test_collectives_counted_inside_scans():
     if devs > 1:
         assert r["collectives"]["total"] > 0
     assert np.isfinite(r["bytes"]) and r["bytes"] > 0
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    """Peaks come from the published table for the device kind JAX reports;
+    a kind that is not in it raises instead of defaulting to v5e."""
+    import pytest
+    from repro.launch import roofline
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                           16 * 10**9)
+    assert v5e.ici_bw == 1600e9 / 8          # 1,600 Gbit/s
+    assert roofline.LINK_BW == v5e.ici_bw
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("TPU v9000")

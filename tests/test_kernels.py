@@ -323,19 +323,23 @@ def test_fused_update_segsum_B8192_no_quadratic_intermediate():
 
 
 @pytest.mark.slow
-def test_sgns_step_fused2_chunked_launches_B5120():
+def test_sgns_step_fused2_chunked_launches_B5120(monkeypatch):
     """B past the plan's VMEM chunk limit: ops.sgns_step must split into
     sequential fused launches that match a ref oracle applied with the SAME
-    chunk boundaries (chunking = coarser-grained sequential SGD)."""
+    chunk boundaries (chunking = coarser-grained sequential SGD). The plan
+    is made against a 16 MiB budget so that 5120 rows need two launches."""
+    import functools
     from repro.kernels import ops
     Nv = Nc = 1024
     d, B, S = 128, 5120, 16
+    monkeypatch.setattr(ops, "plan_fused_update", functools.partial(
+        ops.plan_fused_update, vmem_budget=16 * 2**20))
     plan = ops.plan_fused_update(B, d, S, jnp.float32)
     assert plan.chunk_rows < B, plan    # the point of the test
     vert, ctx, iv, ic, inn, mask = _step_inputs(Nv, Nc, B, S, d, kbase=150)
     lr = jnp.float32(0.05)
-    v1, c1, l1 = ops.sgns_step(vert, ctx, iv, ic, inn, mask, lr,
-                               impl="pallas_fused2")
+    v1, c1, l1 = ops.sgns_step.__wrapped__(vert, ctx, iv, ic, inn, mask, lr,
+                                           impl="pallas_fused2")
     v0, c0, loss0 = vert, ctx, 0.0
     for s in range(0, B, plan.chunk_rows):
         e = min(s + plan.chunk_rows, B)

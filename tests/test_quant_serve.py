@@ -213,26 +213,31 @@ def test_store_quant_overfetch_override():
 
 # -------------------------------------------------------------- planner
 def test_choose_block_n_respects_vmem_budget():
-    """At shapes whose (2*bn, d) double-buffer would bust a 16 MB budget
-    the planner shrinks the tile until the modeled working set fits (half
-    budget, headroom for compiler temporaries); small shapes keep the cap."""
-    budget = roofline.VMEM_BYTES
+    """At shapes whose (2*bn, d) double-buffer would bust the planner
+    budget (below the scoped VMEM limit every scan launch requests) the
+    planner shrinks the tile until the modeled working set fits; small
+    shapes keep the cap."""
+    budget = roofline.VMEM_BUDGET_BYTES
+    assert budget < roofline.VMEM_LIMIT_BYTES
     for d, dtype in [(4096, jnp.float32), (8192, jnp.float32),
                      (8192, jnp.bfloat16)]:
         bn = tk.choose_block_n(d, dtype)
-        assert tk.topk_scan_vmem_bytes(bn, d, dtype) <= budget // 2, (d, bn)
+        assert tk.topk_scan_vmem_bytes(bn, d, dtype) <= budget, (d, bn)
         assert bn >= 8
-        # the default-256 tile of PR 3 would NOT have fit at d=8192 f32
-    assert tk.topk_scan_vmem_bytes(256, 8192, jnp.float32) > budget // 2
+    # the capped 512-row tile would NOT fit at d=8192 f32
+    assert tk.topk_scan_vmem_bytes(512, 8192, jnp.float32) > budget
     # small shapes: the cap, not the budget, binds
     assert tk.choose_block_n(64, jnp.float32) == 512
     # int8 tiles are 4x denser, so the planner can afford bigger tiles
     assert (tk.choose_block_n(8192, jnp.int8)
             >= tk.choose_block_n(8192, jnp.float32))
-    # d so large the resident (bq, d) query block alone is half the
+    # the model counts lane padding: a k=10 list costs a full 128 lanes
+    assert (tk.topk_scan_vmem_bytes(256, 128, jnp.float32, k=10)
+            == tk.topk_scan_vmem_bytes(256, 128, jnp.float32, k=128))
+    # d so large the double-buffered (bq, d) query block alone busts the
     # budget: the planner bottoms out at the sublane floor (the tile is
     # no longer what busts VMEM — shrinking block_q is the caller's knob)
-    assert tk.choose_block_n(16384, jnp.int8) == 8
+    assert tk.choose_block_n(65536, jnp.int8) == 8
 
 
 def test_choose_block_n_default_paths_are_exact():
