@@ -158,6 +158,8 @@ def build_episode_fn(mesh: Mesh, part: NodePartition, cfg: HybridConfig):
     _pool = [None]
     _lr = [None]
 
+    # the step's stable name in the profiler trace (its ops' metadata)
+    @jax.named_scope("episode_step")
     def episode_device_fn(vert, ctx, blocks, counts, pool, seed, lr):
         # local views; vert becomes a k-tuple of sub-part arrays (see
         # model_round) — the split/concat happen once per episode, not per

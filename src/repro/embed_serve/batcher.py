@@ -28,6 +28,7 @@ responses tagged as such.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -35,8 +36,8 @@ from concurrent.futures import Future
 
 import numpy as np
 
-from repro.obs import (gauge_set, observe, register_source, span,
-                       trace_counter, unregister_source)
+from repro.obs import (active, gauge_set, observe, register_source, span,
+                       trace_counter, tracer, unregister_source)
 from repro.runtime import DeadlineExceeded, Overloaded
 
 _CLOSE = object()
@@ -138,9 +139,10 @@ class MicroBatcher:
                 ) from None
         else:
             self._queue.put((q, fut, dl, t_sub))
-        depth = self._queue.qsize()
-        gauge_set("serve.queue_depth", depth)
-        trace_counter("serve.queue_depth", depth)
+        if active() is not None or tracer() is not None:
+            depth = self._queue.qsize()     # takes the queue's lock
+            gauge_set("serve.queue_depth", depth)
+            trace_counter("serve.queue_depth", depth)
         # a close() racing the check above either drains this item (worker
         # backlog or close's cancel loop) or already finished draining —
         # `_drained` was set before that final drain, so seeing it here
@@ -191,33 +193,36 @@ class MicroBatcher:
         self.close()
 
     # ------------------------------------------------------------- worker
+    # Each batch's spans (track "serve") carry its sequence number as
+    # ``batch``: collect -> stack -> backend -> fanout.
     def _worker(self):
-        while True:
+        for seq in itertools.count():
             item = self._queue.get()
             if item is _CLOSE:
-                self._drain()
+                self._drain(seq)
                 return
-            batch = [item]
-            deadline = time.perf_counter() + self._window_s
-            closing = False
-            while len(batch) < self._max_batch:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    nxt = self._queue.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if nxt is _CLOSE:
-                    closing = True
-                    break
-                batch.append(nxt)
-            self._run(batch)
+            with span("batcher.collect", "serve", {"batch": seq}):
+                batch = [item]
+                deadline = time.perf_counter() + self._window_s
+                closing = False
+                while len(batch) < self._max_batch:
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        nxt = self._queue.get(timeout=remaining)
+                    except queue.Empty:
+                        break
+                    if nxt is _CLOSE:
+                        closing = True
+                        break
+                    batch.append(nxt)
+            self._run(batch, seq)
             if closing:
-                self._drain()
+                self._drain(seq + 1)
                 return
 
-    def _drain(self):
+    def _drain(self, seq: int):
         """Serve whatever was queued before the close sentinel."""
         batch = []
         while True:
@@ -229,57 +234,62 @@ class MicroBatcher:
                 continue
             batch.append(item)
             if len(batch) == self._max_batch:
-                self._run(batch)
+                self._run(batch, seq)
+                seq += 1
                 batch = []
         if batch:
-            self._run(batch)
+            self._run(batch, seq)
 
-    def _run(self, batch):
-        # expire first: a request whose deadline passed while queued gets
-        # DeadlineExceeded, never a late answer
-        now = time.perf_counter()
-        live = []
-        for q, fut, dl, t_sub in batch:
-            if dl is not None and now > dl:
+    def _run(self, batch, seq: int):
+        with span("batcher.stack", "serve", {"batch": seq}):
+            # expire first: a request whose deadline passed while queued
+            # gets DeadlineExceeded, never a late answer
+            now = time.perf_counter()
+            live = []
+            for q, fut, dl, t_sub in batch:
+                if dl is not None and now > dl:
+                    if fut.set_running_or_notify_cancel():
+                        fut.set_exception(DeadlineExceeded(
+                            f"request expired {now - dl:.3f}s past its "
+                            f"deadline before serving"))
+                        with self._stats_mu:
+                            self.stats.expired += 1
+                    continue
                 if fut.set_running_or_notify_cancel():
-                    fut.set_exception(DeadlineExceeded(
-                        f"request expired {now - dl:.3f}s past its "
-                        f"deadline before serving"))
-                    with self._stats_mu:
-                        self.stats.expired += 1
-                continue
-            if fut.set_running_or_notify_cancel():
-                live.append((q, fut, t_sub))
-        if not live:
-            return
-        qs = np.stack([q for q, _, _ in live])
-        B = qs.shape[0]
-        Bp = -(-B // self._pad) * self._pad
-        if Bp > B:                      # pad rows: results are discarded
-            qs = np.concatenate(
-                [qs, np.zeros((Bp - B, self._dim), qs.dtype)])
+                    live.append((q, fut, t_sub))
+            if not live:
+                return
+            qs = np.stack([q for q, _, _ in live])
+            B = qs.shape[0]
+            Bp = -(-B // self._pad) * self._pad
+            if Bp > B:                  # pad rows: results are discarded
+                qs = np.concatenate(
+                    [qs, np.zeros((Bp - B, self._dim), qs.dtype)])
         try:
-            with span("serve_batch", "serve", {"batch": B, "padded": Bp}):
+            with span("batcher.backend", "serve",
+                      {"batch": seq, "queries": B, "padded": Bp}):
                 out = self._serve_fn(qs)
         except Exception as e:          # noqa: BLE001 — propagate to callers
-            for _, fut, _ in live:
-                fut.set_exception(e)
+            with span("batcher.fanout", "serve", {"batch": seq}):
+                for _, fut, _ in live:
+                    fut.set_exception(e)
             return
-        # backend returns (vals, ids) or (vals, ids, meta) — a degraded-scan
-        # tag (TopKMeta) is attached to every request of the batch
-        meta = out[2] if len(out) == 3 else None
-        vals, ids = out[0], out[1]
-        t_done = time.perf_counter()
-        for i, (_, fut, t_sub) in enumerate(live):
-            row = (np.asarray(vals[i]), np.asarray(ids[i]))
-            fut.set_result(row if meta is None else row + (meta,))
-            observe("serve.request_s", t_done - t_sub)  # admission -> served
-        with self._stats_mu:
-            self.stats.requests += B
-            self.stats.batches += 1
-            self.stats.padded_rows += Bp - B
-            if meta is not None and getattr(meta, "degraded", False):
-                self.stats.degraded += len(live)
+        with span("batcher.fanout", "serve", {"batch": seq}):
+            # backend returns (vals, ids) or (vals, ids, meta) — a
+            # degraded-scan tag (TopKMeta) is attached to every request
+            meta = out[2] if len(out) == 3 else None
+            vals, ids = out[0], out[1]
+            t_done = time.perf_counter()
+            for i, (_, fut, t_sub) in enumerate(live):
+                row = (np.asarray(vals[i]), np.asarray(ids[i]))
+                fut.set_result(row if meta is None else row + (meta,))
+                observe("serve.request_s", t_done - t_sub)  # admission->served
+            with self._stats_mu:
+                self.stats.requests += B
+                self.stats.batches += 1
+                self.stats.padded_rows += Bp - B
+                if meta is not None and getattr(meta, "degraded", False):
+                    self.stats.degraded += len(live)
 
 
 def drive_open_loop(batcher: MicroBatcher, queries, *, qps: float = 0.0,

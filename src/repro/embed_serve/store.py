@@ -52,7 +52,7 @@ from repro.embed_serve import quant as qz
 from repro.embed_serve import topk as tk
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
-from repro.obs import counter_add, gauge_set
+from repro.obs import counter_add, gauge_set, span
 from repro.runtime import fault_point
 from repro.train.checkpoint import load_arrays
 
@@ -445,30 +445,52 @@ class ShardedEmbeddingStore:
         return_meta=True appends a :class:`TopKMeta` (degraded flag +
         failed shard list) to the return tuple.
         """
-        impl = self.resolve_impl(impl)
-        ov = self.overfetch if overfetch is None else overfetch
-        k = min(k, self.num_nodes)
-        q = jnp.asarray(np.asarray(queries, dtype=np.float32))
-        timeout = (self.shard_timeout_s if shard_timeout_s is _UNSET
-                   else shard_timeout_s)
-        live = [s for s in range(len(self.shards)) if self.valid[s] > 0]
+        with span("embed_store.topk", "serve"):
+            impl = self.resolve_impl(impl)
+            ov = self.overfetch if overfetch is None else overfetch
+            k = min(k, self.num_nodes)
+            with span("embed_store.to_device", "serve"):
+                q = jnp.asarray(np.asarray(queries, dtype=np.float32))
+            timeout = (self.shard_timeout_s if shard_timeout_s is _UNSET
+                       else shard_timeout_s)
+            live = [s for s in range(len(self.shards)) if self.valid[s] > 0]
 
-        if timeout is None:
-            # fast path (unchanged from the always-healthy store): dispatch
-            # every shard before syncing any — jax dispatch is async, so P
-            # devices scan concurrently instead of one behind the other
-            launched = [self._dispatch_shard(s, q, k, impl, ov)
-                        for s in live]
-            staged = jax.device_get(launched)
-            gv, gi = self._merge([v for v, _ in staged],
-                                 [i for _, i in staged], k)
-            if impl == "tiered":
-                self._note_tiered_result(gi)
-            return (gv, gi, TopKMeta()) if return_meta else (gv, gi)
+            if timeout is None:
+                # fast path (unchanged from the always-healthy store):
+                # dispatch every shard before syncing any — jax dispatch is
+                # async, so P devices scan concurrently instead of one
+                # behind the other
+                with span("embed_store.dispatch", "serve"):
+                    launched = [self._dispatch_shard(s, q, k, impl, ov)
+                                for s in live]
+                with span("embed_store.fetch", "serve"):
+                    staged = jax.device_get(launched)
+                per_v = [v for v, _ in staged]
+                per_i = [i for _, i in staged]
+                meta = TopKMeta()
+            else:
+                per_v, per_i, failed = self._scan_within(
+                    live, q, k, impl, ov, timeout)
+                meta = TopKMeta(degraded=bool(failed),
+                                failed_shards=tuple(sorted(failed)),
+                                timeout_s=timeout)
+            with span("embed_store.merge", "serve"):
+                gv, gi = self._merge(per_v, per_i, k)
+                if impl == "tiered":
+                    self._note_tiered_result(gi)
+        return (gv, gi, meta) if return_meta else (gv, gi)
 
+    def _scan_within(self, live, q, k: int, impl: str, ov: float,
+                     timeout: float):
+        """Each live shard's scan as its own task; returns the per-shard
+        (scores, ids) of those that answered within ``timeout``, and the
+        failed shards."""
         def scan(s):
             fault_point("serve.shard", (s,))
-            return jax.device_get(self._dispatch_shard(s, q, k, impl, ov))
+            with span("embed_store.dispatch", "serve", {"shard": s}):
+                launched = self._dispatch_shard(s, q, k, impl, ov)
+            with span("embed_store.fetch", "serve", {"shard": s}):
+                return jax.device_get(launched)
 
         pool = self._scan_pool()
         futs = {s: pool.submit(scan, s) for s in live}
@@ -489,14 +511,7 @@ class ShardedEmbeddingStore:
             raise RuntimeError(
                 f"all {len(live)} shard scans failed or timed out "
                 f"({timeout}s); shards: {failed}")
-        gv, gi = self._merge(per_v, per_i, k)
-        if impl == "tiered":
-            self._note_tiered_result(gi)
-        if return_meta:
-            return gv, gi, TopKMeta(degraded=bool(failed),
-                                    failed_shards=tuple(sorted(failed)),
-                                    timeout_s=timeout)
-        return gv, gi
+        return per_v, per_i, failed
 
     def oracle_topk(self, queries, k: int, *, exclude_shards=()):
         """Numpy ground truth over the full (unsharded) table.

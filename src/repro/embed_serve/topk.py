@@ -278,6 +278,7 @@ def _launch_topk_scan(table, scales, queries, *, k: int, valid: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=roofline.VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="topk_mips_quant" if quant else "topk_mips",
     )(*operands)
     return out_v[:Q], out_i[:Q]
 
@@ -428,6 +429,7 @@ def topk_mips_rowwise(table, queries, *, k: int, valid: int | None = None,
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ),
         interpret=interpret,
+        name="topk_mips_rowwise",
     )(table, queries)
 
 

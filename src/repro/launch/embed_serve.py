@@ -114,8 +114,10 @@ def main(argv=None, *, devices=None):
     ap.add_argument("--metrics-interval-s", type=float, default=5.0,
                     help="seconds between metrics.jsonl snapshots")
     ap.add_argument("--trace", default=None, metavar="FILE",
-                    help="record serve_batch spans + queue-depth counter "
-                         "track as Chrome trace-event JSON (ui.perfetto.dev)")
+                    help="record the serving spans (batcher.collect/stack/"
+                         "backend/fanout, embed_store.topk/to_device/"
+                         "dispatch/fetch/merge) + queue-depth counter track "
+                         "as Chrome trace-event JSON (ui.perfetto.dev)")
     args = ap.parse_args(argv)
 
     from repro import obs

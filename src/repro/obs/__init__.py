@@ -2,10 +2,12 @@
 
 Disabled by default. ``enable()`` installs the process-wide registry;
 ``set_tracer(Tracer())`` installs the span recorder. Every hot-path
-helper (``counter_add``/``gauge_set``/``observe``/``span``/``instant``/
+helper (``counter_add``/``gauge_set``/``observe``/``span``/
 ``trace_counter``) is a single module-level ``None`` check while
 disabled — the ``fault_point`` design rule — so instrumented code pays
 nothing until a launcher opts in via ``--metrics-dir`` / ``--trace``.
+``span`` also reads the profiler's own flag: while a ``jax.profiler``
+session records, every span is written into the profiler's trace too.
 """
 from .metrics import (  # noqa: F401
     Counter,
@@ -26,7 +28,6 @@ from .sink import MetricsWriter  # noqa: F401
 from .trace import (  # noqa: F401
     PIPELINE_TRACKS,
     Tracer,
-    instant,
     set_tracer,
     span,
     trace_counter,
@@ -39,5 +40,5 @@ __all__ = [
     "enable", "disable", "active", "enabled",
     "counter_add", "gauge_set", "observe",
     "register_source", "unregister_source",
-    "set_tracer", "tracer", "span", "instant", "trace_counter",
+    "set_tracer", "tracer", "span", "trace_counter",
 ]

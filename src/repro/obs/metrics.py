@@ -8,8 +8,7 @@ module-level helpers (:func:`counter_add`, :func:`gauge_set`,
 established: with no registry installed every helper is a single
 module-level ``None`` check — no allocation, no lock, no dict lookup — so
 the idle cost of fully-instrumented hot paths is provably free (gated by
-the ``obs_idle`` dataflow row in ``BENCH_episode.json`` and a
-zero-allocation test).
+``tests/test_obs.py::test_disabled_helpers_allocate_nothing``).
 
 Three metric kinds:
 

@@ -13,10 +13,21 @@ dynamic lanes (one per walk-worker thread, one per remote producer host)
 are appended as they first emit. ``thread_name``/``thread_sort_index``
 metadata events pin names and order so every run renders the same way.
 
-The module-level :func:`span` helper follows the same design rule as
-``fault_point`` and the metrics helpers: with no tracer installed it is a
-single ``None`` check returning a shared no-op context manager — zero
-allocation on disabled hot paths.
+:func:`span` is the one way the program marks its work, whichever sink
+is listening. With a :class:`Tracer` installed the span is recorded in the
+Chrome JSON. While a ``jax.profiler`` session records, the span also opens
+a ``jax.profiler.TraceAnnotation`` (the profiler's own TraceMe): it lands on
+the profiler trace's host plane, on the same clock as the device's ops,
+with its args as event stats. With neither sink active it follows the
+design rule of ``fault_point`` and the metrics helpers: one ``None`` check
+plus the profiler's own active check, returning a shared no-op context
+manager — zero allocation on disabled hot paths. No profiler can record
+before JAX is imported, so the check binds to JAX's only once JAX is
+loaded, and importing this module imports no JAX.
+
+:meth:`Tracer.add_span` takes explicit endpoints, observed before the
+span's length was known; a TraceMe cannot be backdated, so such spans go
+to the Chrome JSON only.
 
 Spans record wall-clock-anchored microseconds from a monotonic clock
 (``perf_counter``) relative to tracer start. The event buffer is bounded
@@ -27,6 +38,7 @@ a poor observability tool.
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 
@@ -37,8 +49,8 @@ PIPELINE_TRACKS = ("walk", "build", "stage", "train", "store", "serve")
 
 
 class Tracer:
-    """Thread-safe bounded recorder of complete ("X"), instant ("i") and
-    counter ("C") trace events, serialized as Chrome trace-event JSON."""
+    """Thread-safe bounded recorder of complete ("X") and counter ("C")
+    trace events, serialized as Chrome trace-event JSON."""
 
     def __init__(self, max_events: int = 1_000_000):
         self.max_events = max_events
@@ -81,10 +93,6 @@ class Tracer:
              args: dict | None = None) -> "_Span":
         return _Span(self, name, track, args)
 
-    def instant(self, name: str, track: str = "train",
-                args: dict | None = None) -> None:
-        self._push(("i", name, track, self.now_us(), 0.0, args))
-
     def counter(self, name: str, value) -> None:
         """Counter-track sample: Perfetto renders these as a value-over-
         time graph (store residency, serve queue depth)."""
@@ -112,8 +120,6 @@ class Tracer:
                   "name": name, "ts": ts}
             if ph == "X":
                 ev["dur"] = dur
-            elif ph == "i":
-                ev["s"] = "t"            # instant scope: thread
             if args:
                 ev["args"] = args
             out.append(ev)
@@ -127,9 +133,11 @@ class Tracer:
 
 
 class _Span:
-    """Context manager recording one complete event on exit."""
+    """Context manager marking one span in every active sink: a TraceMe
+    while the profiler records, a complete event in the tracer, if any,
+    on exit."""
 
-    __slots__ = ("_tr", "_name", "_track", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_track", "_args", "_t0", "_me")
 
     def __init__(self, tracer, name, track, args):
         self._tr = tracer
@@ -138,12 +146,20 @@ class _Span:
         self._args = args
 
     def __enter__(self):
-        self._t0 = self._tr.now_us()
+        self._me = None
+        if _profiling():
+            self._me = _TraceMe(self._name, **(self._args or {}))
+            self._me.__enter__()
+        if self._tr is not None:
+            self._t0 = self._tr.now_us()
         return self
 
     def __exit__(self, *exc):
-        self._tr.add_span(self._name, self._track, self._t0,
-                          self._tr.now_us(), self._args)
+        if self._tr is not None:
+            self._tr.add_span(self._name, self._track, self._t0,
+                              self._tr.now_us(), self._args)
+        if self._me is not None:
+            self._me.__exit__(*exc)
         return False
 
 
@@ -176,20 +192,31 @@ def tracer() -> Tracer | None:
     return _TRACER
 
 
+def _profiler_unbound() -> bool:
+    """Whether a profiler session records, until JAX is imported: none can
+    before then. Once ``jax.profiler`` is loaded, binds :func:`_profiling`
+    to the profiler's own check and ``_TraceMe`` to its annotation."""
+    global _profiling, _TraceMe
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return False
+    _TraceMe = prof.TraceAnnotation
+    _profiling = prof.TraceAnnotation.is_enabled
+    return _profiling()
+
+
+_profiling = _profiler_unbound
+_TraceMe = None
+
+
 # ------------------------------------------------------- hot-path helpers
-# Same rule as fault_point / metrics: disabled == one None check.
+# Same rule as fault_point / metrics: disabled == one None check (plus the
+# profiler's own flag read).
 def span(name: str, track: str = "train", args: dict | None = None):
     tr = _TRACER
-    if tr is None:
+    if tr is None and not _profiling():
         return _NOOP
     return _Span(tr, name, track, args)
-
-
-def instant(name: str, track: str = "train", args: dict | None = None) -> None:
-    tr = _TRACER
-    if tr is None:
-        return
-    tr.instant(name, track, args)
 
 
 def trace_counter(name: str, value) -> None:

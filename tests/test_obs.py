@@ -11,6 +11,8 @@ fatal launcher exception — metrics snapshot included.
 import json
 import math
 import os
+import subprocess
+import sys
 import threading
 import time
 import tracemalloc
@@ -135,7 +137,7 @@ def test_tracer_span_hammer_multithreaded():
 def test_tracer_bounded_past_cap():
     tr = Tracer(max_events=10)
     for _ in range(50):
-        tr.instant("tick", "train")
+        tr.counter("tick", 1)
     assert tr.event_count() == 10
     assert tr.dropped == 40
     assert tr.to_json()["otherData"]["dropped_events"] == 40
@@ -160,7 +162,8 @@ def test_disabled_helpers_allocate_nothing():
             obs.gauge_set("g", 1.0)
             obs.observe("h", 0.5)
             obs.trace_counter("tc", 3)
-            obs.instant("i", "walk")
+            with obs.span("i", "walk"):
+                pass
             with obs_trace.span("s", "train"):
                 pass
 
@@ -177,6 +180,134 @@ def test_disabled_helpers_allocate_nothing():
               if s.traceback[0].filename.startswith(obs_dir)
               and s.size_diff > 0]
     assert not leaked, [str(s) for s in leaked]
+
+
+# ---------------------------------------------------------------------------
+# profiler bridge: obs.span also writes into a recording jax.profiler trace
+# ---------------------------------------------------------------------------
+BATCHER_SPANS = ("batcher.collect", "batcher.stack", "batcher.backend",
+                 "batcher.fanout")
+STORE_SPANS = ("embed_store.topk", "embed_store.to_device",
+               "embed_store.dispatch", "embed_store.fetch",
+               "embed_store.merge")
+
+
+def _profile(directory, body, names, window="test.window"):
+    """Run ``body`` under a profiler session, inside a ``window``
+    annotation; returns the trace's host events of those ``names`` and the
+    window as [(name, start_ns, end_ns, stats)]."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(directory))
+    try:
+        with jax.profiler.TraceAnnotation(window):
+            body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(str(directory), "**",
+                                         "*.xplane.pb"), recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            dict(e.stats)) for e in line.events
+                           if e.name in names or e.name == window)
+    return out
+
+
+@pytest.mark.parametrize("shard_timeout_s", [None, 60.0],
+                         ids=["all_shards", "per_shard_deadline"])
+def test_serving_spans_land_in_the_profiler_trace(tmp_path, shard_timeout_s):
+    """A store behind a batcher, traced by the profiler alone (no Tracer):
+    every serving span is in the trace, inside the window, the store's
+    inside a backend call, each batcher span with its batch's number."""
+    import jax
+    from repro.embed_serve import MicroBatcher, ShardedEmbeddingStore
+
+    assert obs.tracer() is None
+    rng = np.random.default_rng(0)
+    table = rng.normal(size=(64, 8)).astype(np.float32)
+    store = ShardedEmbeddingStore.from_array(
+        table, devices=[jax.devices()[0]] * 2, shard_timeout_s=shard_timeout_s)
+    store.topk(table[:4], 3, impl="xla")          # compile before the trace
+
+    def body():
+        with MicroBatcher(lambda q: store.topk(q, 3, impl="xla"), 8,
+                          max_batch=4, window_ms=1.0) as b:
+            for f in [b.submit(q) for q in table[:10]]:
+                f.result(timeout=60)
+
+    evs = _profile(tmp_path, body, BATCHER_SPANS + STORE_SPANS)
+    (lo, hi), = [(s, e) for n, s, e, _ in evs if n == "test.window"]
+    mine = [ev for ev in evs if ev[0] != "test.window"]
+    assert {n for n, *_ in mine} == set(BATCHER_SPANS + STORE_SPANS)
+    assert all(lo <= s <= e <= hi for _, s, e, _ in mine)
+    backends = [(s, e) for n, s, e, _ in mine if n == "batcher.backend"]
+    for n, s, e, _ in mine:
+        if n in STORE_SPANS:
+            assert any(bs <= s and e <= be for bs, be in backends), n
+    by_batch = {}
+    for n, _, _, st in mine:
+        if n in BATCHER_SPANS:
+            by_batch.setdefault(st["batch"], set()).add(n)
+    assert len(by_batch) >= 3                        # 10 queries, 4 a batch
+    assert all(names == set(BATCHER_SPANS) for names in by_batch.values())
+    if shard_timeout_s is not None:                  # one fetch per shard
+        shards = [st["shard"] for n, _, _, st in mine
+                  if n == "embed_store.fetch"]
+        assert sorted(set(shards)) == [0, 1]
+
+
+def test_one_span_lands_in_both_sinks(tmp_path):
+    tr = Tracer()
+    obs.set_tracer(tr)
+
+    def body():
+        with obs.span("both.sinks", "serve", {"batch": 7}):
+            time.sleep(0.001)
+
+    evs = _profile(tmp_path, body, ("both.sinks",))
+    assert [st for n, _, _, st in evs if n == "both.sinks"] == [{"batch": 7}]
+    xs = [e for e in tr.to_json()["traceEvents"]
+          if e["ph"] == "X" and e["name"] == "both.sinks"]
+    assert len(xs) == 1 and xs[0]["args"] == {"batch": 7}
+
+
+def test_span_enters_a_trace_annotation_only_while_the_profiler_records(
+        tmp_path):
+    import jax
+    assert obs.span("a", "serve") is obs_trace._NOOP
+    seen = []
+
+    def body():
+        s = obs.span("b", "serve", {"batch": 1})
+        assert s is not obs_trace._NOOP
+        with s:
+            seen.append(s._me)
+
+    _profile(tmp_path, body, ())
+    assert isinstance(seen[0], jax.profiler.TraceAnnotation)
+    assert obs.span("c", "serve") is obs_trace._NOOP
+
+
+def test_importing_obs_imports_no_jax():
+    """Walk producers import obs: it must load no JAX (and so start no
+    backend), and a span there stays the shared no-op."""
+    code = ("import sys\n"
+            "from repro import obs\n"
+            "from repro.obs import trace\n"
+            "assert obs.span('x', 'walk') is trace._NOOP\n"
+            "assert not any(m == 'jax' or m.startswith('jax.')\n"
+            "               for m in sys.modules), 'obs imports jax'\n")
+    r = subprocess.run([sys.executable, "-c", code],
+                       env=dict(os.environ, PYTHONPATH=os.path.join(
+                           os.path.dirname(os.path.dirname(
+                               os.path.abspath(__file__))), "src")),
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ cache off, since an entry compiled for a described chip cannot be read
 back without one.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -64,6 +65,14 @@ def _compile(fn, *args, donate=()):
     return compiled
 
 
+def _kernel_names(text):
+    """The HLO names of the Pallas kernels in a compiled module, without
+    their numeric suffix: what the trace readers match."""
+    return {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", text)}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("B,combine", [(256, "eq"), (8192, "segsum"),
@@ -93,10 +102,15 @@ def test_sgns_step_fused2_compiles(one_chip, dtype, B, combine):
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("k", [10, 100])
 def test_topk_mips_compiles(one_chip, dtype, k):
+    """The exact scan keeps its name inside a larger jitted program."""
     from repro.embed_serve import topk as tk
-    _compile(lambda t, q: tk.topk_mips(t, q, k=k, interpret=False),
-             _spec((TABLE_ROWS, D), dtype, one_chip),
-             _spec((256, D), jnp.float32, one_chip))
+
+    def serve_step(t, q):
+        v, i = tk.topk_mips(t, q, k=k, interpret=False)
+        return v * 2.0, i + 1
+    compiled = _compile(serve_step, _spec((TABLE_ROWS, D), dtype, one_chip),
+                        _spec((256, D), jnp.float32, one_chip))
+    assert _kernel_names(compiled.as_text()) == {"topk_mips"}
 
 
 @pytest.mark.parametrize("m", [40, 400])
@@ -105,13 +119,34 @@ def test_topk_mips_quant_compiles(one_chip, m):
     first pass over-fetching m = 4k candidates (k=10 and k=100), then the
     kernel gather and exact rescore of the survivors."""
     from repro.embed_serve import quant as qz
-    _compile(lambda t, t8, s, q: qz.topk_mips_quant_rescored(
-                 t, t8, s, q, k=m // 4, overfetch=4.0, impl="pallas",
-                 interpret=False),
-             _spec((TABLE_ROWS, D), jnp.float32, one_chip),
-             _spec((TABLE_ROWS, D), jnp.int8, one_chip),
-             _spec((TABLE_ROWS,), jnp.float32, one_chip),
-             _spec((256, D), jnp.float32, one_chip))
+    compiled = _compile(lambda t, t8, s, q: qz.topk_mips_quant_rescored(
+                            t, t8, s, q, k=m // 4, overfetch=4.0,
+                            impl="pallas", interpret=False),
+                        _spec((TABLE_ROWS, D), jnp.float32, one_chip),
+                        _spec((TABLE_ROWS, D), jnp.int8, one_chip),
+                        _spec((TABLE_ROWS,), jnp.float32, one_chip),
+                        _spec((256, D), jnp.float32, one_chip))
+    assert "topk_mips_quant" in _kernel_names(compiled.as_text())
+
+
+def test_topk_rowwise_kernel_is_named():
+    """The rowwise scan is the interpret-mode reference: the TPU compiler
+    refuses its one-row blocks, so its name is read from the program
+    before lowering."""
+    from repro.embed_serve import topk as tk
+    jaxpr = jax.make_jaxpr(lambda t, q: tk.topk_mips_rowwise(
+        t, q, k=4, interpret=True))(jnp.zeros((16, D)), jnp.zeros((2, D)))
+    names = [e.params["name"] for e in _eqns(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert names == ["topk_mips_rowwise"]
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -146,6 +181,7 @@ def test_episode_step_compiles(topo, one_chip, chips):
     compiled = fn.lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert "/episode_step/" in text            # the step's named scope
     if chips > 1:
         assert "collective-permute" in text       # the ring rotation
     ma = compiled.memory_analysis()
