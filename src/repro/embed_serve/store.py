@@ -206,19 +206,22 @@ class ShardedEmbeddingStore:
         return self.shards[0].shape[1]
 
     def _dispatch_shard(self, s: int, q, k: int, impl: str, ov: float):
-        """Dispatch shard s's scan (async) → (scores, GLOBAL ids) device
-        arrays. Sub-k shards keep the IDX_SENTINEL so they lose the merge."""
+        """Dispatch shard s's scan (async) → (scores, GLOBAL ids, groups)
+        device arrays. Sub-k shards keep the IDX_SENTINEL so they lose the
+        merge. groups is the Pallas scan's (2,) merge-gate counts
+        (``topk.topk_mips_counted``), None on every other path."""
         if impl == "tiered":
             # hot/cold split scans carry their own global-id maps
-            return self._dispatch_shard_tiered(s, q, k, ov)
+            return (*self._dispatch_shard_tiered(s, q, k, ov), None)
         shard = self.shards[s]
         interpret = (kops.interpret_mode()
                      if impl in ("pallas", "rowwise", "quant_pallas")
                      else False)
+        groups = None
         if impl == "pallas":
-            v, i = tk.topk_mips(shard, q, k=k, valid=self.valid[s],
-                                block_n=self.block_n,
-                                interpret=interpret)
+            v, i, groups = tk.topk_mips_counted(
+                shard, q, k=k, valid=self.valid[s], block_n=self.block_n,
+                interpret=interpret)
         elif impl == "rowwise":
             v, i = tk.topk_mips_rowwise(shard, q, k=k,
                                         valid=self.valid[s],
@@ -236,7 +239,7 @@ class ShardedEmbeddingStore:
         # (elementwise, overlaps the other shards' scans)
         rows = self.part.padded_rows_per_shard
         gi = jnp.where(i == tk.IDX_SENTINEL, tk.IDX_SENTINEL, i + s * rows)
-        return v, gi
+        return v, gi, groups
 
     # ------------------------------------------------------------ hot tier
     def enable_hot_tier(self, budget: int, *, ids=None, counts=None) -> int:
@@ -465,8 +468,9 @@ class ShardedEmbeddingStore:
                                 for s in live]
                 with span("embed_store.fetch", "serve"):
                     staged = jax.device_get(launched)
-                per_v = [v for v, _ in staged]
-                per_i = [i for _, i in staged]
+                per_v = [v for v, _, _ in staged]
+                per_i = [i for _, i, _ in staged]
+                _note_scan_groups([g for _, _, g in staged])
                 meta = TopKMeta()
             else:
                 per_v, per_i, failed = self._scan_within(
@@ -500,9 +504,10 @@ class ShardedEmbeddingStore:
         per_v, per_i, failed = [], [], []
         for s, f in futs.items():
             if f.done() and f.exception() is None:
-                v, i = f.result()
+                v, i, groups = f.result()
                 per_v.append(v)
                 per_i.append(i)
+                _note_scan_groups([groups])
             else:
                 # timed out (result, if it ever lands, is discarded) or
                 # crashed — either way the shard is out of this answer
@@ -548,6 +553,16 @@ class ShardedEmbeddingStore:
         q = np.asarray(queries, dtype=np.float32)
         rows = self.host_table.astype(np.float32)[np.asarray(ids)]  # (Q,k,d)
         return np.einsum("qd,qkd->qk", q, rows)
+
+
+def _note_scan_groups(groups) -> None:
+    """Add the fetched merge-gate counts of the Pallas scans (None for the
+    other paths) to ``serve.topk.merge_groups`` (row-group merges run) and
+    ``serve.topk.tile_groups`` (row groups x tiles scanned)."""
+    for g in groups:
+        if g is not None:
+            counter_add("serve.topk.merge_groups", int(g[0]))
+            counter_add("serve.topk.tile_groups", int(g[1]))
 
 
 def recall_at_k(got_ids, oracle_ids, *, got_vals=None, oracle_vals=None,

@@ -20,8 +20,11 @@ from repro.core.partition import build_episode_blocks
 from repro.embed_serve import (MicroBatcher, ShardedEmbeddingStore,
                                merge_topk, topk_mips, topk_mips_rowwise,
                                topk_mips_xla)
+from repro.embed_serve import topk as tk
 from repro.kernels import ref
 from repro.train.checkpoint import load_arrays, save_checkpoint
+
+import topk_gate_cases as gate
 
 
 def _int_table(n, d, seed=0, dtype=jnp.float32, lo=-4, hi=5):
@@ -71,6 +74,50 @@ def test_topk_mips_padded_shard_masked():
     assert int(np.asarray(i).max()) < 40
     rv, ri = ref.topk_mips_ref(np.asarray(tbl)[:40], np.asarray(q), 5)
     np.testing.assert_array_equal(np.asarray(i), ri)
+
+
+@pytest.mark.parametrize("name", gate.CASES)
+def test_topk_mips_merge_gate_is_exact(name):
+    """The merge gate returns bitwise what the jnp path does on row orders
+    that merge every tile, only the first, tie at the k-th value across a
+    tile boundary, end valid rows inside a tile or below k, pad the query
+    block, and open one row group of a block alone; its counts match the
+    row order."""
+    c = gate.case(name, 10)
+    v, i, groups = tk.topk_mips_counted(
+        jnp.asarray(c["table"]), jnp.asarray(c["queries"]), k=10,
+        valid=c["valid"], block_q=c["block_q"], block_n=gate.BLOCK_N,
+        interpret=True)
+    xv, xi = topk_mips_xla(jnp.asarray(c["table"]),
+                           jnp.asarray(c["queries"]), k=10, valid=c["valid"])
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(xi))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(xv))
+    gate.check_merges(c, groups)
+    pv, pi = topk_mips(jnp.asarray(c["table"]), jnp.asarray(c["queries"]),
+                       k=10, valid=c["valid"], block_q=c["block_q"],
+                       block_n=gate.BLOCK_N, interpret=True)
+    np.testing.assert_array_equal(np.asarray(pi), np.asarray(xi))
+    np.testing.assert_array_equal(np.asarray(pv), np.asarray(xv))
+
+
+@pytest.mark.parametrize("impl,merged,visited", [("pallas", 2, 8),
+                                                 ("xla", 0, 0)])
+def test_store_counts_scan_merges(impl, merged, visited):
+    """The store adds the Pallas scan's gate counts to the obs counters
+    serve.topk.merge_groups / serve.topk.tile_groups; other paths add
+    nothing."""
+    from repro import obs
+    c = gate.case("falling", 10)
+    store = ShardedEmbeddingStore.from_array(c["table"],
+                                             block_n=gate.BLOCK_N)
+    reg = obs.enable()
+    try:
+        store.topk(c["queries"], 10, impl=impl)
+        counters = reg.snapshot()["counters"]
+    finally:
+        obs.disable()
+    assert counters.get("serve.topk.merge_groups", 0) == merged
+    assert counters.get("serve.topk.tile_groups", 0) == visited
 
 
 @pytest.mark.parametrize("fn", [topk_mips_rowwise, topk_mips_xla],

@@ -20,6 +20,8 @@ from repro.embed_serve import topk as tk
 from repro.kernels import ref
 from repro.launch import roofline
 
+import topk_gate_cases as gate
+
 
 def _int_table(n, d, seed=0, dtype=jnp.float32, lo=-4, hi=5):
     rng = np.random.default_rng(seed)
@@ -87,6 +89,27 @@ def test_topk_quant_kernel_matches_xla(dtype, N, Q, m):
                                     m=m, valid=N)
     np.testing.assert_array_equal(np.asarray(ki), np.asarray(xi))
     np.testing.assert_array_equal(np.asarray(kv), np.asarray(xv))
+
+
+@pytest.mark.parametrize("name", gate.CASES)
+def test_topk_quant_merge_gate_is_exact(name):
+    """The int8 pass shares the exact scan's gated body: at m=40 it returns
+    bitwise what the jnp path does on the gate's edge cases, and its
+    counts match the row order."""
+    m = 40
+    c = gate.case(name, m)
+    q8, sc = qz.quantize_rows(c["table"])
+    q8, sc, q = jnp.asarray(q8), jnp.asarray(sc), jnp.asarray(c["queries"])
+    kv, ki = tk.topk_mips_quant(q8, sc, q, m=m, valid=c["valid"],
+                                block_q=c["block_q"], block_n=gate.BLOCK_N,
+                                interpret=True)
+    xv, xi = tk.topk_mips_quant_xla(q8, sc, q, m=m, valid=c["valid"])
+    np.testing.assert_array_equal(np.asarray(ki), np.asarray(xi))
+    np.testing.assert_array_equal(np.asarray(kv), np.asarray(xv))
+    _, _, groups = tk._launch_topk_scan(
+        q8, sc.reshape(1, -1), q, k=m, valid=c["valid"],
+        bq=min(c["block_q"], q.shape[0]), bn=gate.BLOCK_N, interpret=True)
+    gate.check_merges(c, groups)
 
 
 def test_topk_quant_padded_rows_masked():

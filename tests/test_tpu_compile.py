@@ -29,6 +29,7 @@ from repro.kernels import ops
 D = 128            # the paper configuration's width
 NEGATIVES = 5
 TABLE_ROWS = 1 << 20
+CELL_ROWS = 6_562_500   # one chip's share of the paper's Anonymized A table
 
 
 @pytest.fixture(scope="module")
@@ -109,6 +110,24 @@ def test_topk_mips_compiles(one_chip, dtype, k):
         v, i = tk.topk_mips(t, q, k=k, interpret=False)
         return v * 2.0, i + 1
     compiled = _compile(serve_step, _spec((TABLE_ROWS, D), dtype, one_chip),
+                        _spec((256, D), jnp.float32, one_chip))
+    assert _kernel_names(compiled.as_text()) == {"topk_mips"}
+
+
+@pytest.mark.parametrize("k", [10, 100])
+def test_topk_mips_compiles_at_cell_shape(one_chip, k):
+    """The gated scan as the store calls it in the serving cell (merge
+    counts included): the f32 shard of 6,562,500 valid rows padded to
+    512-row tiles, two 128-row query blocks, the planner's 512-row tile."""
+    from repro.embed_serve import topk as tk
+    assert tk.choose_block_n(D, jnp.float32, k=k) == 512
+
+    def serve_step(t, q):
+        v, i, groups = tk.topk_mips_counted(t, q, k=k, valid=CELL_ROWS,
+                                            block_n=512, interpret=False)
+        return v * 2.0, i + 1, groups
+    rows = -(-CELL_ROWS // 512) * 512
+    compiled = _compile(serve_step, _spec((rows, D), jnp.float32, one_chip),
                         _spec((256, D), jnp.float32, one_chip))
     assert _kernel_names(compiled.as_text()) == {"topk_mips"}
 
