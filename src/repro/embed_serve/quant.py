@@ -196,11 +196,12 @@ def topk_mips_quant_rescored_counted(table, qtable, scales, queries, *,
                                     block_n: int | None = None,
                                     impl: str = "pallas",
                                     interpret: bool = False):
-    """:func:`topk_mips_quant_rescored` and the first pass's merge-gate
-    counts, as the store calls it: ((Q, k) f32, (Q, k) i32, (2,) i32
-    [row-group merges run, row groups x tiles scanned] of the Pallas int8
-    pass, None on the "xla" path). The two tiers stay two jitted programs
-    (``topk_mips_quant_counted``, then :func:`rescore_exact`)."""
+    """:func:`topk_mips_quant_rescored` and the first pass's counts, as
+    the store calls it: ((Q, k) f32, (Q, k) i32, (4,) i32 [row-group
+    merges run, (row group, tile) pairs scored, query blocks, zero query
+    blocks] of the Pallas int8 pass, None on the "xla" path). The two
+    tiers stay two jitted programs (``topk_mips_quant_counted``, then
+    :func:`rescore_exact`)."""
     if impl not in ("pallas", "xla"):
         raise ValueError(f"unknown quant impl {impl!r}")
     n_rows = valid if valid is not None else qtable.shape[0]

@@ -208,7 +208,7 @@ class ShardedEmbeddingStore:
     def _dispatch_shard(self, s: int, q, k: int, impl: str, ov: float):
         """Dispatch shard s's scan (async) → (scores, GLOBAL ids, groups)
         device arrays. Sub-k shards keep the IDX_SENTINEL so they lose the
-        merge. groups is the Pallas scan's (2,) merge-gate counts
+        merge. groups is the Pallas scan's (4,) counts
         (``topk.topk_mips_counted``, or the int8 first pass's), None on
         every other path. The quant paths add the rows their rescore
         gathers to ``serve.quant.candidates``."""
@@ -570,16 +570,20 @@ def _quantize_shards(shards) -> list:
 
 
 def _note_scan_groups(groups, impl: str) -> None:
-    """Add the fetched merge-gate counts of the Pallas scans (None for the
-    other paths) to ``<scan>.merge_groups`` (row-group merges run) and
-    ``<scan>.tile_groups`` (row groups x tiles scanned), where ``<scan>``
-    is ``serve.quant`` for the int8 first pass and ``serve.topk`` for the
+    """Add the fetched counts of the Pallas scans (None for the other
+    paths) to ``<scan>.merge_groups`` (row-group merges run),
+    ``<scan>.tile_groups`` ((row group, tile) pairs scored),
+    ``<scan>.query_blocks`` (query blocks launched) and
+    ``<scan>.zero_blocks`` (those of zero rows alone, scored only on the
+    tiles holding their first k rows), where ``<scan>`` is
+    ``serve.quant`` for the int8 first pass and ``serve.topk`` for the
     exact scan, so a process serving both never mixes them."""
     scan = "serve.quant" if impl.startswith("quant") else "serve.topk"
     for g in groups:
         if g is not None:
-            counter_add(f"{scan}.merge_groups", int(g[0]))
-            counter_add(f"{scan}.tile_groups", int(g[1]))
+            for name, n in zip(("merge_groups", "tile_groups",
+                                "query_blocks", "zero_blocks"), g):
+                counter_add(f"{scan}.{name}", int(n))
 
 
 def recall_at_k(got_ids, oracle_ids, *, got_vals=None, oracle_vals=None,

@@ -10,16 +10,16 @@ Kernels (all validated against :func:`repro.kernels.ref.topk_mips_ref`):
 
   * :func:`topk_mips`          — the production kernel: the table stays in
     HBM; (bn, d) row tiles are double-buffered into VMEM by explicit DMA
-    (tile t+1's copy flies while tile t is scored on the MXU), and each
-    query block folds the tiles into a running (bq, k) top-k held in the
-    revisited output block. One HBM read per table row per query block.
-    :func:`topk_mips_counted` is the same scan returning the merge gate's
-    counts too (the store's Pallas path).
+    (tile t+1's copy flies while tile t is scored on the MXU), and the
+    call's query blocks, resident in VMEM, each fold every tile into a
+    running (bq, k) top-k held in the revisited output block. One HBM
+    read per table row per call. :func:`topk_mips_counted` is the same
+    scan returning its counts too (the store's Pallas path).
   * :func:`topk_mips_quant`    — the int8 first pass of the two-tier
     quantized scan (``embed_serve.quant``): the same double-buffered
     tile-DMA skeleton streaming (bn, d) *int8* tiles (4x less DMA traffic
     than f32), per-row scales riding a pipelined (1, bn) block, keeping an
-    over-fetched running top-``m`` candidate set per query block. Its
+    over-fetched running top-``m`` candidate set per query row. Its
     output is approximate by the quantization error — survivors are
     re-scored exactly by ``quant.rescore_exact``.
     :func:`topk_mips_quant_counted` returns the merge gate's counts too.
@@ -56,6 +56,14 @@ through the data: a row order with rising scores merges the block on
 every tile, at the cost of the ungated kernel plus the gate (about
 0.35 µs a step on a v5e, 5% of the ungated step at k = 10).
 
+Zero blocks: serving pads each call to a fixed batch with zero rows, so
+a call's last query blocks often hold no query at all. A zero row's
+scores are all +-0; once the tiles holding its first k rows are merged,
+its k-th value is 0 and no later tile can open it. So a query block of
+zero rows alone is scored on those tiles only, and costs nothing after
+them; the launcher flags each block's zeros from the queries it is
+given.
+
 Exactness: scores are f32 (tables cast up before the dot, like the SGNS
 kernels), selection is exact MIPS with ties broken toward the smaller row
 index — the same total order as the numpy oracle's stable argsort.
@@ -79,8 +87,8 @@ from repro.launch import roofline
 
 NEG_INF = float("-inf")
 IDX_SENTINEL = jnp.iinfo(jnp.int32).max
-DEFAULT_BLOCK_Q = 128   # query rows per resident block (topk_mips default);
-                        # the table is re-scanned once per query block
+DEFAULT_BLOCK_Q = 128   # query rows scored and merged as one block
+                        # (topk_mips default)
 DEFAULT_PLAN_K = 128    # running-candidate allowance choose_block_n assumes
                         # when the query-time k is not yet known (store load)
 # every score is an f32 product at full precision (the TPU's default rounds
@@ -99,32 +107,36 @@ def _lanes(n: int) -> int:
 
 def topk_scan_vmem_bytes(bn: int, d: int, dtype, *, k: int = DEFAULT_PLAN_K,
                          block_q: int = DEFAULT_BLOCK_Q,
+                         rows: int | None = None,
                          hot_rows: int = 0) -> int:
     """Modeled VMEM working set of one topk_mips/topk_mips_quant launch.
 
     Mirrors the scratch_shapes, the pipelined blocks and the compute
     temporaries, with every column count padded to whole 128-lane tiles:
     the (2*bn, d) double-buffer tile slots (table dtype), the f32 cast of
-    the scored tile, the double-buffered (bq, d) query block, the int8
-    scan's double-buffered (1, bn) scale block (8 sublanes), the (bq, bn)
-    score/iota matrices, the (bq, k + bn) candidates the selection loop
-    carries (vals/idx plus the per-pass masked copies — 4 f32-width
-    copies), and the double-buffered (bq, k) value/id output blocks.
+    the scored tile, the double-buffered (rows, d) resident queries, the
+    int8 scan's double-buffered (1, bn) scale block (8 sublanes), the
+    (block_q, bn) score/iota matrices, the (block_q, k + bn) candidates
+    the selection loop carries (vals/idx plus the per-pass masked copies
+    — 4 f32-width copies), and the double-buffered (rows, k) value/id
+    output blocks. rows (default block_q) is the query rows the launch
+    keeps resident: whole query blocks, scored one at a time.
 
     hot_rows models a co-resident hot-tier scan tile: the tiered store
     runs an exact-f32 scan over min(hot_rows, bn) rows alongside the quant
     scan of the cold remainder, so that tile's bytes come out of the same
     budget. 0 (default) is the untiered model.
     """
+    rows = block_q if rows is None else rows
     item = jnp.dtype(dtype).itemsize
     total = 2 * bn * d * item                     # double-buffered tile slots
     total += bn * d * 4                           # f32 cast of the scored tile
-    total += 2 * block_q * _lanes(d) * 4          # query block
+    total += 2 * rows * _lanes(d) * 4             # resident queries
     if item == 1:
         total += 2 * 8 * _lanes(bn) * 4           # int8 row-scale block
     total += block_q * _lanes(bn) * 4 * 2         # (bq, bn) scores + iota
     total += block_q * _lanes(k + bn) * 4 * 4     # selection candidates
-    total += 2 * 2 * block_q * _lanes(k) * 4      # running (bq, k) outputs
+    total += 2 * 2 * rows * _lanes(k) * 4         # running (rows, k) outputs
     total += min(hot_rows, bn) * d * 4            # exact hot-tier scan tile
     return total
 
@@ -203,17 +215,29 @@ def _merge_into(out_v_ref, out_i_ref, scores, gidx, k: int):
 GROUP_ROWS = 8   # query rows of the smallest merge: an f32 sublane group
 
 
-def _topk_scan_kernel(*refs, k: int, bn: int, valid: int, quant: bool):
-    """One (query block, table tile) step of the scan.
+def _topk_scan_kernel(live_ref, *refs, k: int, bn: int, bq: int,
+                      valid: int, quant: bool):
+    """One table tile's step of the scan, for every resident query block.
 
-    The tile is scored into the (bq, bn) ``scores_s`` scratch and merged
-    only where it can change a running top-k: a row opens when its best
-    score in the tile is strictly above its running k-th value. No open
-    row: the step ends. Open rows in one GROUP_ROWS-row group: the exact
-    ``select_topk`` merge runs on that group's rows alone. Open rows in
-    two or more groups: it runs once on the whole block, which costs
-    about as much as two group merges. ``merges_ref`` counts the row
-    groups merged (a block merge counts every group of the block).
+    The queries of the launch's row group stay resident, and so do their
+    running (rows, k) lists; each step waits for its tile's DMA, starts
+    the next one, and then scores the tile against each query block in
+    turn. ``live_ref`` holds one flag a query block (1 if any entry is
+    non-zero): a block of all-zero rows is scored only on the tiles that
+    hold its first k rows, which give it its exact answer (every score
+    is +-0, ties go to the smaller id); a later tile's +-0 scores equal
+    its running k-th value and could not open the gate below.
+
+    A scored block's tile lands in the (bq, bn) ``scores_s`` scratch and
+    is merged only where it can change a running top-k: a row opens when
+    its best score in the tile is strictly above its running k-th value.
+    No open row: the block's step ends. Open rows in one GROUP_ROWS-row
+    group: the exact ``select_topk`` merge runs on that group's rows
+    alone. Open rows in two or more groups: it runs once on the whole
+    block, which costs about as much as two group merges. ``counts_ref``
+    counts, in lane 0, the row groups merged (a block merge counts every
+    group of the block) and, in lane 1, the (row group, tile) pairs
+    scored.
 
     The strict ``>`` is exact: tiles arrive in ascending row order (the
     table axis is the sequential inner grid axis), so a later score equal
@@ -224,17 +248,16 @@ def _topk_scan_kernel(*refs, k: int, bn: int, valid: int, quant: bool):
     opens. Padded rows (global index >= valid) score -inf and so never
     open a row, and carry the IDX_SENTINEL id into a merge.
     """
-    if quant:
-        (tbl_hbm, scale_ref, q_ref, out_v_ref, out_i_ref, merges_ref,
-         tile_s, scores_s, sem) = refs
-    else:
-        (tbl_hbm, q_ref, out_v_ref, out_i_ref, merges_ref,
-         tile_s, scores_s, sem) = refs
-        scale_ref = None
+    tbl_hbm, scale_ref, *refs = refs if quant else (refs[0], None,
+                                                     *refs[1:])
+    (q_ref, out_v_ref, out_i_ref, counts_ref, tile_s, scores_s, sem,
+     *cast_s) = refs           # cast_s: the f32 tile, for non-f32 tables
+    g = pl.program_id(0)
     t = pl.program_id(1)
     T = pl.num_programs(1)
     f32 = jnp.float32
-    bq = scores_s.shape[0]
+    nb = q_ref.shape[0] // bq
+    lane = jax.lax.broadcasted_iota(jnp.int32, counts_ref.shape, 1)
 
     def tile_copy(tt, op):
         """start/wait tile tt's contiguous-row DMA on buffer slot tt % 2."""
@@ -244,11 +267,11 @@ def _topk_scan_kernel(*refs, k: int, bn: int, valid: int, quant: bool):
             sem.at[tt % 2]), op)()
 
     @pl.when(t == 0)
-    def _prologue():           # new query block: restart the tile pipeline
+    def _prologue():           # new query row group: restart the pipeline
         tile_copy(0, "start")
         out_v_ref[...] = jnp.full_like(out_v_ref, NEG_INF)
         out_i_ref[...] = jnp.full_like(out_i_ref, IDX_SENTINEL)
-        merges_ref[...] = jnp.zeros_like(merges_ref)
+        counts_ref[...] = jnp.zeros_like(counts_ref)
 
     @pl.when(t + 1 < T)
     def _prefetch_next():      # double buffering: next tile's DMA flies
@@ -256,46 +279,82 @@ def _topk_scan_kernel(*refs, k: int, bn: int, valid: int, quant: bool):
 
     tile_copy(t, "wait")
 
-    tile = tile_s[pl.ds((t % 2) * bn, bn), :]
-    scores = jax.lax.dot_general(q_ref[...].astype(f32), tile.astype(f32),
-                                 (((1,), (1,)), ((), ())),
-                                 precision=_F32,
-                                 preferred_element_type=f32)
-    if scale_ref is not None:  # int8 tiles: back to embedding units
-        scores = scores * scale_ref[...]
-    scores_s[...] = scores
+    tile = tile_s.at[pl.ds((t % 2) * bn, bn)]
+    if cast_s:                 # int8 / bf16 tiles: cast once, not per block
+        cast_s[0][...] = tile[...].astype(f32)
+        tile = cast_s[0]
 
-    @pl.when((t + 1) * bn > valid)
-    def _demote_padding():     # only tiles that reach past the valid rows
-        col = t * bn + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        scores_s[...] = jnp.where(col >= valid, NEG_INF, scores_s[...])
+    def scan_block(b: int):
+        """Score the tile against query block b and merge where it opens."""
+        block = slice(b * bq, (b + 1) * bq)
+        scores = jax.lax.dot_general(q_ref[block, :].astype(f32),
+                                     tile[...].astype(f32),
+                                     (((1,), (1,)), ((), ())),
+                                     precision=_F32,
+                                     preferred_element_type=f32)
+        if scale_ref is not None:  # int8 tiles: back to embedding units
+            scores = scores * scale_ref[...]
+        scores_s[...] = scores
 
-    def merge(rows, n: int):
-        """Merge the tile into the running lists of ``rows`` (n rows);
-        padded rows carry the IDX_SENTINEL id."""
-        col = t * bn + jax.lax.broadcasted_iota(jnp.int32, (n, bn), 1)
-        _merge_into(out_v_ref.at[rows], out_i_ref.at[rows],
-                    scores_s[rows, :],
-                    jnp.where(col >= valid, IDX_SENTINEL, col), k)
+        @pl.when((t + 1) * bn > valid)
+        def _demote_padding():     # only tiles that reach past the valid rows
+            col = t * bn + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            scores_s[...] = jnp.where(col >= valid, NEG_INF, scores_s[...])
 
-    # a row opens where its best score in the tile beats its running k-th
-    # value (the minimum of its descending running list)
-    best = jnp.max(scores_s[...], axis=1, keepdims=True)
-    kth = jnp.min(out_v_ref[...], axis=1, keepdims=True)
-    group = jax.lax.broadcasted_iota(jnp.int32, best.shape, 0) // GROUP_ROWS
-    lo = jnp.min(jnp.where(best > kth, group, bq))   # bq: no row opens
-    hi = jnp.max(jnp.where(best > kth, group, -1))
+        def merge(lo, n: int):
+            """Merge the tile into the running lists of the block's n rows
+            from row lo; padded rows carry the IDX_SENTINEL id."""
+            col = t * bn + jax.lax.broadcasted_iota(jnp.int32, (n, bn), 1)
+            rows = pl.ds(pl.multiple_of(b * bq + lo, GROUP_ROWS), n)
+            _merge_into(out_v_ref.at[rows], out_i_ref.at[rows],
+                        scores_s[pl.ds(lo, n), :],
+                        jnp.where(col >= valid, IDX_SENTINEL, col), k)
 
-    @pl.when(lo == hi)         # one group opens: merge its rows alone
-    def _merge_group():
-        merge(pl.ds(pl.multiple_of(lo * GROUP_ROWS, GROUP_ROWS),
-                    GROUP_ROWS), GROUP_ROWS)
-        merges_ref[...] += 1
+        # a row opens where its best score in the tile beats its running
+        # k-th value (the minimum of its descending running list)
+        best = jnp.max(scores_s[...], axis=1, keepdims=True)
+        kth = jnp.min(out_v_ref[block, :], axis=1, keepdims=True)
+        group = (jax.lax.broadcasted_iota(jnp.int32, best.shape, 0)
+                 // GROUP_ROWS)
+        lo = jnp.min(jnp.where(best > kth, group, bq))   # bq: no row opens
+        hi = jnp.max(jnp.where(best > kth, group, -1))
+        counts_ref[...] += jnp.where(lane == 1, bq // GROUP_ROWS, 0)
 
-    @pl.when(lo < hi)          # two or more: one merge of the whole block
-    def _merge_block():
-        merge(slice(None), bq)
-        merges_ref[...] += bq // GROUP_ROWS
+        @pl.when(lo == hi)         # one group opens: merge its rows alone
+        def _merge_group():
+            merge(pl.multiple_of(lo * GROUP_ROWS, GROUP_ROWS), GROUP_ROWS)
+            counts_ref[...] += jnp.where(lane == 0, 1, 0)
+
+        @pl.when(lo < hi)          # two or more: one merge of the whole block
+        def _merge_block():
+            merge(0, bq)
+            counts_ref[...] += jnp.where(lane == 0, bq // GROUP_ROWS, 0)
+
+    for b in range(nb):        # a zero block stops after its first k rows
+        pl.when((live_ref[g * nb + b] != 0) | (t * bn < k))(
+            functools.partial(scan_block, b))
+
+
+MAX_RESIDENT_BLOCKS = 8   # query blocks one launch keeps resident: each is
+                          # its own copy of the block body in the kernel
+
+
+def resident_query_blocks(blocks: int, bq: int, bn: int, d: int, dtype, *,
+                          k: int,
+                          vmem_budget: int = roofline.VMEM_BUDGET_BYTES
+                          ) -> int:
+    """Query blocks of bq rows one scan launch keeps resident at tile bn.
+
+    The most blocks, up to ``blocks`` and MAX_RESIDENT_BLOCKS, whose
+    queries and running lists fit the planner budget beside the tile
+    (:func:`topk_scan_vmem_bytes`); floor 1. A launch with more blocks
+    runs its row groups as an outer grid axis, each scanning the table.
+    """
+    nb = min(blocks, MAX_RESIDENT_BLOCKS)
+    while nb > 1 and topk_scan_vmem_bytes(
+            bn, d, dtype, k=k, block_q=bq, rows=nb * bq) > vmem_budget:
+        nb -= 1
+    return nb
 
 
 def _launch_topk_scan(table, scales, queries, *, k: int, valid: int,
@@ -304,9 +363,12 @@ def _launch_topk_scan(table, scales, queries, *, k: int, valid: int,
 
     scales=None is the exact scan; a (1, N) f32 scales row makes it the
     int8 first pass. bq is rounded up to whole GROUP_ROWS groups (padded
-    query rows are zero). Returns the unpadded ((Q, k) f32, (Q, k) i32)
-    and the merge gate's (2,) i32 counts: row-group merges run, and row
-    groups x tiles scanned."""
+    query rows are zero). The grid is (row groups, table tiles): at most
+    :func:`resident_query_blocks` blocks a row group, one row group for
+    the store's calls, so each table tile is read once a call. Returns
+    the unpadded ((Q, k) f32, (Q, k) i32) and the scan's (4,) i32 counts:
+    row-group merges run, (row group, tile) pairs scored, query blocks,
+    and query blocks of zero rows alone."""
     N, d = table.shape
     Q = queries.shape[0]
     quant = scales is not None
@@ -318,44 +380,57 @@ def _launch_topk_scan(table, scales, queries, *, k: int, valid: int,
                              constant_values=1.0)
         N = table.shape[0]
     bq = -(-bq // GROUP_ROWS) * GROUP_ROWS
-    Qp = -(-Q // bq) * bq
-    qp = jnp.pad(queries, ((0, Qp - Q), (0, 0)))
-    grid = (Qp // bq, N // bn)        # table tiles innermost (sequential)
+    blocks = -(-Q // bq)
+    nb = resident_query_blocks(blocks, bq, bn, d, table.dtype, k=k)
+    n_groups = -(-blocks // nb)
+    nb = -(-blocks // n_groups)       # spread the blocks evenly
+    rows = nb * bq
+    qp = jnp.pad(queries, ((0, n_groups * rows - Q), (0, 0)))
+    live = jnp.any(qp.reshape(n_groups * nb, bq * d) != 0,
+                   axis=1).astype(jnp.int32)   # NaN counts as non-zero
+    grid = (n_groups, N // bn)        # table tiles innermost (sequential)
     in_specs = [pl.BlockSpec(memory_space=pl.ANY)]       # table (HBM)
     operands = [table]
     if quant:
-        in_specs.append(pl.BlockSpec((1, bn), lambda qi, t: (0, t)))
+        in_specs.append(pl.BlockSpec((1, bn), lambda g, t, _: (0, t)))
         operands.append(scales)                             # row scales
-    in_specs.append(pl.BlockSpec((bq, d), lambda qi, t: (qi, 0)))
-    operands.append(qp)                                     # query block
-    out_v, out_i, merges = pl.pallas_call(
-        functools.partial(_topk_scan_kernel, k=k, bn=bn, valid=valid,
-                          quant=quant),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((bq, k), lambda qi, t: (qi, 0)),   # running top-k
-            pl.BlockSpec((bq, k), lambda qi, t: (qi, 0)),
-            pl.BlockSpec((8, 128), lambda qi, t: (qi, 0)),  # group merges
+    in_specs.append(pl.BlockSpec((rows, d), lambda g, t, _: (g, 0)))
+    operands.append(qp)                                     # resident queries
+    scratch = [
+        pltpu.VMEM((2 * bn, d), table.dtype),               # tile slots
+        pltpu.VMEM((bq, bn), jnp.float32),                  # tile scores
+        pltpu.SemaphoreType.DMA((2,)),
+    ]
+    if table.dtype != jnp.float32:
+        scratch.append(pltpu.VMEM((bn, d), jnp.float32))    # f32 tile
+    out_v, out_i, counts = pl.pallas_call(
+        functools.partial(_topk_scan_kernel, k=k, bn=bn, bq=bq,
+                          valid=valid, quant=quant),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                          # live flags
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=(
+                pl.BlockSpec((rows, k), lambda g, t, _: (g, 0)),  # top-k
+                pl.BlockSpec((rows, k), lambda g, t, _: (g, 0)),
+                pl.BlockSpec((8, 128), lambda g, t, _: (g, 0)),   # counts
+            ),
+            scratch_shapes=scratch,
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((Qp, k), jnp.float32),
-            jax.ShapeDtypeStruct((Qp, k), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0] * 8, 128), jnp.int32),
+            jax.ShapeDtypeStruct((n_groups * rows, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_groups * rows, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_groups * 8, 128), jnp.int32),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((2 * bn, d), table.dtype),           # tile slots
-            pltpu.VMEM((bq, bn), jnp.float32),              # tile scores
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=roofline.VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="topk_mips_quant" if quant else "topk_mips",
-    )(*operands)
-    groups = jnp.stack([jnp.sum(merges[::8, 0]),
-                        jnp.int32(grid[0] * grid[1] * bq // GROUP_ROWS)])
-    return out_v[:Q], out_i[:Q], groups
+    )(live, *operands)
+    counts = jnp.stack([jnp.sum(counts[::8, 0]), jnp.sum(counts[::8, 1]),
+                        jnp.int32(live.shape[0]),
+                        jnp.int32(live.shape[0]) - jnp.sum(live)])
+    return out_v[:Q], out_i[:Q], counts
 
 
 @functools.partial(jax.jit, static_argnames=("k", "valid", "block_q",
@@ -365,8 +440,9 @@ def topk_mips_counted(table, queries, *, k: int, valid: int | None = None,
                       block_n: int | None = None, interpret: bool = False):
     """:func:`topk_mips` and the merge gate's counts, as the store calls it.
 
-    Returns ((Q, k) f32 scores, (Q, k) i32 shard-local row ids, (2,) i32
-    [row-group merges run, row groups x tiles scanned]).
+    Returns ((Q, k) f32 scores, (Q, k) i32 shard-local row ids, (4,) i32
+    [row-group merges run, (row group, tile) pairs scored, query blocks,
+    query blocks of zero rows alone]).
     """
     N, d = table.shape
     valid = N if valid is None else valid
@@ -421,7 +497,8 @@ def topk_mips_quant_counted(qtable, scales, queries, *, m: int,
     calls it.
 
     Returns ((Q, m) f32 approx scores, (Q, m) i32 shard-local row ids,
-    (2,) i32 [row-group merges run, row groups x tiles scanned]).
+    (4,) i32 [row-group merges run, (row group, tile) pairs scored, query
+    blocks, query blocks of zero rows alone]).
     """
     N, d = qtable.shape
     valid = N if valid is None else valid

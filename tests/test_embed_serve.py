@@ -78,34 +78,77 @@ def test_topk_mips_padded_shard_masked():
 
 @pytest.mark.parametrize("name", gate.CASES)
 def test_topk_mips_merge_gate_is_exact(name):
-    """The merge gate returns bitwise what the jnp path does on row orders
-    that merge every tile, only the first, tie at the k-th value across a
-    tile boundary, end valid rows inside a tile or below k, pad the query
-    block, and open one row group of a block alone; its counts match the
-    row order."""
+    """The merge gate returns bitwise what the jnp path and the numpy
+    oracle do on row orders that merge every tile, only the first, tie at
+    the k-th value across a tile boundary, end valid rows inside a tile or
+    below k, pad the query block, and open one row group of a block
+    alone, and on calls whose query blocks hold zero rows alone (after,
+    between and without live blocks, past one tile, below k valid rows)
+    or a zero row among live ones; its counts match the row order."""
     c = gate.case(name, 10)
-    v, i, groups = tk.topk_mips_counted(
-        jnp.asarray(c["table"]), jnp.asarray(c["queries"]), k=10,
-        valid=c["valid"], block_q=c["block_q"], block_n=gate.BLOCK_N,
-        interpret=True)
-    xv, xi = topk_mips_xla(jnp.asarray(c["table"]),
-                           jnp.asarray(c["queries"]), k=10, valid=c["valid"])
+    kw = dict(k=10, valid=c["valid"], block_q=c["block_q"],
+              block_n=c["block_n"], interpret=True)
+    tbl, q = jnp.asarray(c["table"]), jnp.asarray(c["queries"])
+    v, i, groups = tk.topk_mips_counted(tbl, q, **kw)
+    xv, xi = topk_mips_xla(tbl, q, k=10, valid=c["valid"])
     np.testing.assert_array_equal(np.asarray(i), np.asarray(xi))
     np.testing.assert_array_equal(np.asarray(v), np.asarray(xv))
+    gate.check_oracle(c, v, i)
     gate.check_merges(c, groups)
-    pv, pi = topk_mips(jnp.asarray(c["table"]), jnp.asarray(c["queries"]),
-                       k=10, valid=c["valid"], block_q=c["block_q"],
-                       block_n=gate.BLOCK_N, interpret=True)
+    pv, pi = topk_mips(tbl, q, **kw)
     np.testing.assert_array_equal(np.asarray(pi), np.asarray(xi))
     np.testing.assert_array_equal(np.asarray(pv), np.asarray(xv))
+
+
+def test_topk_mips_row_groups_past_residency():
+    """More query blocks than one launch keeps resident run as row groups
+    of an outer grid axis, each scanning the table: 9 blocks of 8 rows
+    over MAX_RESIDENT_BLOCKS = 8 become 2 groups of 5 (one block of
+    padding); block 4 and the padding are zero rows. The answer is the
+    jnp path's and the oracle's, bitwise."""
+    assert tk.MAX_RESIDENT_BLOCKS == 8
+    tbl = _int_table(256, 16, seed=30)
+    q = np.array(_int_table(72, 16, seed=31))
+    q[32:40] = 0.0
+    v, i, groups = tk.topk_mips_counted(tbl, jnp.asarray(q), k=10,
+                                        block_q=8, block_n=64,
+                                        interpret=True)
+    xv, xi = topk_mips_xla(tbl, jnp.asarray(q), k=10)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(xi))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(xv))
+    rv, ri = ref.topk_mips_ref(np.asarray(tbl), q, 10)
+    np.testing.assert_array_equal(np.asarray(i), ri)
+    np.testing.assert_array_equal(np.asarray(v), rv)
+    merged, scored, blocks, zero = (int(x) for x in np.asarray(groups))
+    assert (blocks, zero) == (10, 2)
+    assert scored == 8 * 4 + 2 * 1       # live blocks 4 tiles, zero ones 1
+    assert 10 <= merged <= scored
+
+
+def test_resident_query_blocks_fit_the_budget():
+    """The planner keeps every block of a serving call resident at the
+    cells' shapes, caps the count at MAX_RESIDENT_BLOCKS, and drops to
+    one block where the budget holds no more."""
+    for dtype, k in ((jnp.float32, 10), (jnp.float32, 100), (jnp.int8, 40),
+                     (jnp.int8, 400)):
+        assert tk.resident_query_blocks(2, 128, 512, 128, dtype, k=k) == 2
+        nb = tk.resident_query_blocks(64, 128, 512, 128, dtype, k=k)
+        assert nb == tk.MAX_RESIDENT_BLOCKS
+        assert (tk.topk_scan_vmem_bytes(512, 128, dtype, k=k, rows=nb * 128)
+                <= tk.roofline.VMEM_BUDGET_BYTES)
+    one = tk.topk_scan_vmem_bytes(512, 128, jnp.float32, k=10, rows=128)
+    assert tk.resident_query_blocks(2, 128, 512, 128, jnp.float32, k=10,
+                                    vmem_budget=one) == 1
 
 
 @pytest.mark.parametrize("impl,merged,visited", [("pallas", 2, 8),
                                                  ("xla", 0, 0)])
 def test_store_counts_scan_merges(impl, merged, visited):
-    """The store adds the Pallas scan's gate counts to the obs counters
-    serve.topk.merge_groups / serve.topk.tile_groups; other paths add
-    nothing."""
+    """The store adds the Pallas scan's counts to the obs counters
+    serve.topk.merge_groups / tile_groups / query_blocks / zero_blocks;
+    other paths add nothing. 16 positive queries are one live block of
+    two row groups, each scored on the table's 4 tiles and merged on the
+    first alone."""
     from repro import obs
     c = gate.case("falling", 10)
     store = ShardedEmbeddingStore.from_array(c["table"],
@@ -118,6 +161,40 @@ def test_store_counts_scan_merges(impl, merged, visited):
         obs.disable()
     assert counters.get("serve.topk.merge_groups", 0) == merged
     assert counters.get("serve.topk.tile_groups", 0) == visited
+    assert counters.get("serve.topk.query_blocks", 0) == (impl == "pallas")
+    assert counters.get("serve.topk.zero_blocks", 0) == 0
+
+
+@pytest.mark.parametrize("impl,scan", [("pallas", "serve.topk"),
+                                       ("quant_pallas", "serve.quant")])
+def test_store_counts_zero_blocks_of_a_padded_call(impl, scan):
+    """A 256-row call with 5 real rows, as the batcher pads it: on each of
+    two shards (2 tiles of 64 rows) the second 128-row query block is zero
+    rows alone and is scored on the tile holding its first k (or m) rows
+    only, so a shard scores 16 row groups x 2 tiles + 16 x 1; falling
+    rows merge each block's 16 groups once, on tile 0. The answer is the
+    oracle's, for the real rows and the zero ones."""
+    from repro import obs
+    c = gate.case("falling", 10)
+    dev = jax.devices()[0]
+    store = ShardedEmbeddingStore.from_array(
+        c["table"], devices=[dev, dev], block_n=gate.BLOCK_N,
+        quant="int8" if impl.startswith("quant") else None)
+    q = np.zeros((256, gate.D), np.float32)
+    q[:5] = c["queries"][:5]
+    reg = obs.enable()
+    try:
+        v, i = store.topk(q, 10, impl=impl)
+        counters = reg.snapshot()["counters"]
+    finally:
+        obs.disable()
+    assert counters[f"{scan}.query_blocks"] == 2 * 2
+    assert counters[f"{scan}.zero_blocks"] == 2 * 1
+    assert counters[f"{scan}.tile_groups"] == 2 * (16 * 2 + 16 * 1)
+    assert counters[f"{scan}.merge_groups"] == 2 * (16 + 16)
+    rv, ri = ref.topk_mips_ref(c["table"], q, 10)
+    np.testing.assert_array_equal(i, ri)
+    np.testing.assert_array_equal(v, rv)
 
 
 @pytest.mark.parametrize("fn", [topk_mips_rowwise, topk_mips_xla],

@@ -94,22 +94,26 @@ def test_topk_quant_kernel_matches_xla(dtype, N, Q, m):
 @pytest.mark.parametrize("name", gate.CASES)
 def test_topk_quant_merge_gate_is_exact(name):
     """The int8 pass shares the exact scan's gated body: at m=40 it returns
-    bitwise what the jnp path does on the gate's edge cases, and its
-    counts match the row order."""
+    bitwise what the jnp path does on the gate's edge cases and on calls
+    with query blocks of zero rows alone; with unit scales, bitwise the
+    numpy oracle's top-m of the int8 rows; and its counts match the row
+    order."""
     m = 40
     c = gate.case(name, m)
     q8, sc = qz.quantize_rows(c["table"])
     q8, sc, q = jnp.asarray(q8), jnp.asarray(sc), jnp.asarray(c["queries"])
-    kv, ki = tk.topk_mips_quant(q8, sc, q, m=m, valid=c["valid"],
-                                block_q=c["block_q"], block_n=gate.BLOCK_N,
-                                interpret=True)
+    kw = dict(m=m, valid=c["valid"], block_q=c["block_q"],
+              block_n=c["block_n"], interpret=True)
+    kv, ki = tk.topk_mips_quant(q8, sc, q, **kw)
     xv, xi = tk.topk_mips_quant_xla(q8, sc, q, m=m, valid=c["valid"])
     np.testing.assert_array_equal(np.asarray(ki), np.asarray(xi))
     np.testing.assert_array_equal(np.asarray(kv), np.asarray(xv))
     _, _, groups = tk._launch_topk_scan(
         q8, sc.reshape(1, -1), q, k=m, valid=c["valid"],
-        bq=min(c["block_q"], q.shape[0]), bn=gate.BLOCK_N, interpret=True)
+        bq=min(c["block_q"], q.shape[0]), bn=c["block_n"], interpret=True)
     gate.check_merges(c, groups)
+    uv, ui = tk.topk_mips_quant(q8, jnp.ones_like(sc), q, **kw)
+    gate.check_oracle(c, uv, ui, table=np.asarray(q8))
 
 
 def test_topk_quant_padded_rows_masked():
@@ -391,14 +395,14 @@ def test_store_int8_path_on_bf16_equals_oracle(impl, built):
 @pytest.mark.parametrize("name", gate.CASES)
 def test_topk_quant_counted_matches_and_counts(name):
     """The counted int8 pass returns topk_mips_quant's ids and scores, and
-    counts consistent with the gate: tile groups are row groups x tiles,
-    each row group merges its first tile, and no tile twice."""
+    counts consistent with the gate and the zero-block skip
+    (``topk_gate_cases.check_merges``)."""
     m = 40
     c = gate.case(name, m)
     q8, sc = qz.quantize_rows(c["table"])
     q8, sc, q = jnp.asarray(q8), jnp.asarray(sc), jnp.asarray(c["queries"])
     kw = dict(m=m, valid=c["valid"], block_q=c["block_q"],
-              block_n=gate.BLOCK_N, interpret=True)
+              block_n=c["block_n"], interpret=True)
     cv, ci, groups = tk.topk_mips_quant_counted(q8, sc, q, **kw)
     v, i = tk.topk_mips_quant(q8, sc, q, **kw)
     np.testing.assert_array_equal(np.asarray(ci), np.asarray(i))
@@ -435,8 +439,11 @@ def test_store_int8_counters_and_quantize_span(impl, counted):
     assert counters["serve.quant.candidates"] == 2 * Q * 40
     assert not any(n.startswith("serve.topk.") for n in counters)
     if counted:
-        tiles = 2 * (Q // 8) * 2       # two shards x row groups x 2 tiles
-        assert counters["serve.quant.tile_groups"] == tiles
-        assert tiles // 2 <= counters["serve.quant.merge_groups"] <= tiles
+        # two shards x row groups x 2 tiles scored; falling rows: each
+        # group merges its shard's first tile alone; one live block a shard
+        assert counters["serve.quant.tile_groups"] == 2 * (Q // 8) * 2
+        assert counters["serve.quant.merge_groups"] == 2 * (Q // 8)
+        assert counters["serve.quant.query_blocks"] == 2
+        assert counters["serve.quant.zero_blocks"] == 0
     else:
         assert "serve.quant.tile_groups" not in counters

@@ -130,6 +130,18 @@ def test_topk_mips_compiles_at_cell_shape(one_chip, k):
     compiled = _compile(serve_step, _spec((rows, D), jnp.float32, one_chip),
                         _spec((256, D), jnp.float32, one_chip))
     assert _kernel_names(compiled.as_text()) == {"topk_mips"}
+    assert _scan_grids(serve_step, (rows, D), jnp.float32) == [
+        (1, rows // 512)]
+
+
+def _scan_grids(fn, table_shape, dtype, *args):
+    """The grids of the Pallas calls ``fn(table, queries, *args)`` makes
+    at 256 query rows: one table pass a call is (1, N / bn)."""
+    jaxpr = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct(table_shape, dtype),
+                               *args,
+                               jax.ShapeDtypeStruct((256, D), jnp.float32))
+    return [tuple(e.params["grid_mapping"].grid) for e in _eqns(jaxpr.jaxpr)
+            if e.primitive.name == "pallas_call"]
 
 
 @pytest.mark.parametrize("m", [40, 400])
@@ -169,6 +181,11 @@ def test_store_int8_path_compiles_at_cell_shape(one_chip):
         _spec((rows,), jnp.float32, one_chip), q, m=m, valid=A40_ROWS,
         block_n=512).compile()
     assert _kernel_names(first.as_text()) == {"topk_mips_quant"}
+    assert _scan_grids(
+        lambda t, s, q: tk.topk_mips_quant_counted(
+            t, s, q, m=m, valid=A40_ROWS, block_n=512),
+        (rows, D), jnp.int8, jax.ShapeDtypeStruct((rows,), jnp.float32)
+    ) == [(1, rows // 512)]
     rescore = qz.rescore_exact.lower(
         _spec((rows, D), jnp.bfloat16, one_chip), q,
         _spec((256, m), jnp.int32, one_chip), k=10,

@@ -1,21 +1,28 @@
-"""Row orders that drive the top-k scan's merge gate
-(``topk._topk_scan_kernel``) to each of its edges, shared by the exact and
-the int8 scan's tests.
+"""Row orders and query layouts that drive the top-k scan's merge gate
+and its zero-block skip (``topk._topk_scan_kernel``) to each of their
+edges, shared by the exact and the int8 scan's tests.
 
-Every case is integer data at d=16, scanned in block_n=64 tiles, so every
-f32 score is exact (or, on the int8 pass, one rounding of an exact integer)
-and the kernels must agree bitwise with the jnp paths. ``case(name, kk)``
-builds the (N, d) f32 table, the (Q, d) queries, ``valid`` and ``block_q``
-for a running list of kk entries (k, or the int8 pass's m); ``merges``
-gives the row-group merges the gate must run where the row order fixes
-them, else None.
+Every case is integer data at d=16, scanned in block_n=64 tiles unless it
+says otherwise, so every f32 score is exact (or, on the int8 pass, one
+rounding of an exact integer) and the kernels must agree bitwise with the
+jnp paths and the numpy oracle. ``case(name, kk)`` builds the (N, d) f32
+table, the (Q, d) queries, ``valid``, ``block_q`` and ``block_n`` for a
+running list of kk entries (k, or the int8 pass's m); ``merges`` gives
+the row-group merges the gate must run where the row order fixes them,
+else None.
 """
 import numpy as np
 
+from repro.kernels import ref
+
 D = 16
 BLOCK_N = 64
+IDX_SENTINEL = np.iinfo(np.int32).max
 CASES = ["rising", "falling", "tie_at_kth", "tail_valid", "valid_below_k",
-         "zero_queries", "q_not_multiple_of_8", "one_group_opens"]
+         "zero_queries", "q_not_multiple_of_8", "one_group_opens",
+         "zero_block_after_live", "zero_block_between_live",
+         "all_zero_queries", "zero_block_k_above_block_n",
+         "zero_block_valid_below_k", "zero_query_in_live_block"]
 
 
 def _positive(q, seed):
@@ -35,7 +42,8 @@ def _levels(lev):
 
 def case(name: str, kk: int) -> dict:
     n = 4 * BLOCK_N
-    c = {"valid": n, "block_q": 8, "merges": None}
+    c = {"valid": n, "block_q": 8, "block_n": BLOCK_N, "kk": kk,
+         "merges": None}
     if name == "rising":           # every tile beats every row's k-th
         c |= {"table": _levels(1 + np.arange(n)), "queries": _positive(16, 1),
               "merges": 2 * 4}     # 2 row groups x 4 tiles
@@ -79,24 +87,91 @@ def case(name: str, kk: int) -> dict:
         q[8:, :D // 2] = 0.0
         c |= {"table": tbl, "queries": q, "block_q": 16,
               "merges": 2 + 1 + 2}   # tiles 0, 2 (A alone) and 3
+    elif name == "zero_block_after_live":
+        # falling rows: the live group merges tile 0 alone; the zero group
+        # (a zero block) is scored on tile 0 alone, which holds its kk rows
+        q = np.zeros((16, D), np.float32)
+        q[:8] = _positive(8, 13)
+        c |= {"table": _levels(n - np.arange(n)), "queries": q,
+              "merges": 2}
+    elif name == "zero_block_between_live":
+        q = _ints(24, 15)
+        q[8:16] = 0.0
+        c |= {"table": _ints(n, 14), "queries": q}
+    elif name == "all_zero_queries":  # every block is zero: each group
+        c |= {"table": _ints(n, 16),  # merges tile 0 and nothing after
+              "queries": np.zeros((16, D), np.float32), "merges": 2}
+    elif name == "zero_block_k_above_block_n":
+        # 8-row tiles: the zero block's first kk rows span ceil(kk / 8)
+        # tiles, and it merges each of them (its k-th is -inf until then)
+        q = np.zeros((16, D), np.float32)
+        q[:8] = _positive(8, 18)
+        tbl = _levels(64 - np.arange(64))
+        c |= {"table": tbl, "queries": q, "block_n": 8, "valid": 64,
+              "merges": 2 * -(-kk // 8)}   # the live group alike
+    elif name == "zero_block_valid_below_k":
+        # fewer valid rows than kk: the zero rows' answer is the 6 valid
+        # ids, then sentinels; tile 1 is padding alone
+        tbl = _ints(2 * BLOCK_N, 19)
+        tbl[6:] = 4.0
+        q = np.zeros((16, D), np.float32)
+        q[:8] = _ints(8, 20)
+        c |= {"table": tbl, "queries": q, "valid": 6}
+    elif name == "zero_query_in_live_block":
+        q = _ints(16, 22)          # both blocks live; rows 0 and 12 zero
+        q[[0, 12]] = 0.0
+        c |= {"table": _ints(n, 21), "queries": q}
     else:
         raise KeyError(name)
     return c
 
 
+def live_blocks(c: dict) -> np.ndarray:
+    """Per query block of case c (queries padded with zero rows to whole
+    blocks): True where any entry is non-zero."""
+    q = c["queries"]
+    bq = -(-min(c["block_q"], q.shape[0]) // 8) * 8
+    qp = np.zeros((-(-q.shape[0] // bq) * bq, D), np.float32)
+    qp[:q.shape[0]] = q
+    return np.any(qp.reshape(-1, bq * D) != 0, axis=1)
+
+
 def tile_groups(c: dict) -> int:
-    """Row groups x tiles the scan visits for case c."""
-    q = c["queries"].shape[0]
-    bq = -(-min(c["block_q"], q) // 8) * 8
-    return -(-q // bq) * (bq // 8) * (c["table"].shape[0] // BLOCK_N)
+    """(row group, tile) pairs the scan scores for case c: a live block
+    every tile, a zero block the tiles holding its first kk rows."""
+    q = c["queries"]
+    bq = -(-min(c["block_q"], q.shape[0]) // 8) * 8
+    tiles = -(-c["table"].shape[0] // c["block_n"])
+    first = min(tiles, -(-c["kk"] // c["block_n"]))
+    live = live_blocks(c)
+    return (bq // 8) * int(np.sum(np.where(live, tiles, first)))
 
 
 def check_merges(c: dict, groups) -> None:
-    """The gate's (2,) counts for case c: every row group merges its first
-    tile, never more than every tile, and exactly ``merges`` where the row
-    order fixes them."""
-    merged, visited = (int(x) for x in np.asarray(groups))
-    assert visited == tile_groups(c)
-    assert visited // (c["table"].shape[0] // BLOCK_N) <= merged <= visited
+    """The scan's (4,) counts for case c: the query blocks and the zero
+    ones among them, the (row group, tile) pairs scored, every row group
+    merging its first tile and no pair twice, and exactly ``merges``
+    where the row order fixes them."""
+    merged, scored, blocks, zero = (int(x) for x in np.asarray(groups))
+    live = live_blocks(c)
+    assert (blocks, zero) == (live.size, int(np.sum(~live)))
+    assert scored == tile_groups(c)
+    q = c["queries"]
+    row_groups = live.size * (-(-min(c["block_q"], q.shape[0]) // 8))
+    assert row_groups <= merged <= scored
     if c["merges"] is not None:
         assert merged == c["merges"]
+
+
+def check_oracle(c: dict, vals, ids, table=None) -> None:
+    """(vals, ids) are bitwise the numpy oracle's top-kk over the valid
+    rows of ``table`` (default: the case's), and sentinels past them."""
+    t = c["table"] if table is None else table
+    rv, ri = ref.topk_mips_ref(np.asarray(t, np.float32)[:c["valid"]],
+                               c["queries"], c["kk"])
+    n = ri.shape[1]
+    vals, ids = np.asarray(vals), np.asarray(ids)
+    np.testing.assert_array_equal(ids[:, :n], ri)
+    np.testing.assert_array_equal(vals[:, :n], rv)
+    assert np.all(ids[:, n:] == IDX_SENTINEL)
+    assert np.all(vals[:, n:] == -np.inf)
